@@ -1,4 +1,4 @@
-"""smollm-360m [dense] — llama-arch small. [hf:HuggingFaceTB/SmolLM-135M]"""
+"""smollm-360m [dense] — llama-arch small. [hf:HuggingFaceTB/SmolLM-360M]"""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -12,7 +12,7 @@ CONFIG = ArchConfig(
     vocab_size=49152,
     tie_embeddings=True,
     sliding_window=8192,   # long_500k variant only (DESIGN.md §5)
-    source="hf:HuggingFaceTB/SmolLM-135M (360M variant)",
+    source="hf:HuggingFaceTB/SmolLM-360M",
 )
 
 SMOKE = ArchConfig(
@@ -26,5 +26,5 @@ SMOKE = ArchConfig(
     vocab_size=512,
     tie_embeddings=True,
     sliding_window=64,
-    source="reduced variant of hf:HuggingFaceTB/SmolLM-135M",
+    source="reduced variant of hf:HuggingFaceTB/SmolLM-360M",
 )

@@ -396,13 +396,13 @@ class ShardedExecutor(ChunkedExecutor):
         spec = make_flat_spec(params)
         if self._mesh is not None:
             from repro.sharding.specs import flat_group_pspecs
-            spec = with_pspecs(spec, flat_group_pspecs(spec, self._mesh))
+            spec = with_pspecs(spec, flat_group_pspecs(spec, self._mesh),
+                               self._mesh)
         return spec
 
     def _two_tier(self, client_update, params, cohort_batch, client_weights,
                   lr, rng, *, spec, loss_weights=None, codec=None,
                   residuals=None):
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.sharding.specs import axis_size
 
@@ -470,16 +470,16 @@ class ShardedExecutor(ChunkedExecutor):
 
         # the jit is required even under an outer jit: shard_map bodies
         # containing remat/custom_vjp calls cannot be evaluated eagerly
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             tier1, mesh=mesh,
             in_specs=(P(), P(self._ba), P(self._ba), P(self._ba),
                       P(self._ba), P(self._ba)),
             out_specs=(P(), P(), P(self._ba)),
             # the accumulate/aggregate custom_vjp kernels inside the shard
-            # body break shard_map's replication-rule inference
-            check_rep=False))
+            # body break shard_map's varying-axis inference
+            check_vma=False))
         G, loss, res_out = fn(params, cohort_batch, wn, lwn, rngs, res_p)
-        G = constrain_groups(spec, G, mesh)
+        G = constrain_groups(spec, G)
         new_res = None
         if residuals is not None:
             new_res = jax.tree.map(lambda x: x[:cohort], res_out)

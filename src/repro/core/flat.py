@@ -6,9 +6,9 @@ over every parameter, so the pytree structure only costs traversals there.
 This module gives the round engine a *flat* view: leaves are grouped by
 their original dtype, raveled, cast to fp32 and packed into one contiguous
 ``(rows, 128)`` fp32 buffer per dtype group with **static** element offsets
-computed at trace time.  ``rows`` is padded to a multiple of ``row_align``
-(8 = the fp32 sublane tile) so the Pallas kernels in
-``repro.kernels.fused_update`` can tile the buffer directly; the zero pad
+computed at trace time.  ``rows`` is padded to a multiple of
+:data:`ROW_ALIGN` so the Pallas kernels in ``repro.kernels`` can tile the
+buffer directly; the zero pad
 is mathematically inert for every supported optimizer (0-gradient => 0
 update) and is dropped again by :func:`unflatten_tree`.
 
@@ -28,6 +28,12 @@ import numpy as np
 PyTree = Any
 
 LANES = 128           # TPU lane dimension; last axis of every flat buffer
+# Row multiple of every group buffer.  The kernels tile rows by 256: the
+# 1-bit codec packs 8 fp32 rows into one uint8 row, and Mosaic tiles 8-bit
+# arrays by (32, 128), so a packed tile needs 32 * 8 input rows.  A smaller
+# alignment leaves some models with a row count that only an 8-row (and a
+# 1-row packed) tile divides, which the TPU compiler refuses.
+ROW_ALIGN = 256
 
 
 @dataclasses.dataclass(frozen=True)
@@ -57,13 +63,16 @@ class GroupSpec:
 class FlatSpec:
     treedef: Any
     groups: Tuple[GroupSpec, ...]
+    # the jax.sharding.Mesh the group pspecs refer to (set with them by
+    # with_pspecs); None = single-device buffers
+    mesh: Any = None
 
     @property
     def num_leaves(self) -> int:
         return sum(len(g.leaves) for g in self.groups)
 
 
-def make_flat_spec(tree: PyTree, *, row_align: int = 8) -> FlatSpec:
+def make_flat_spec(tree: PyTree) -> FlatSpec:
     """Build the static layout for ``tree`` (works on arrays or
     ShapeDtypeStructs).  Groups are keyed by original leaf dtype in first-
     appearance order; offsets follow tree-flatten order within a group."""
@@ -81,7 +90,7 @@ def make_flat_spec(tree: PyTree, *, row_align: int = 8) -> FlatSpec:
                                   offset=off, size=size))
             off += size
         rows = -(-off // LANES)                      # ceil
-        rows = -(-rows // row_align) * row_align     # pad to sublane tile
+        rows = -(-rows // ROW_ALIGN) * ROW_ALIGN     # pad to the row tile
         groups.append(GroupSpec(dtype=dt, leaves=tuple(specs), size=off,
                                 rows=rows))
     return FlatSpec(treedef=treedef, groups=tuple(groups))
@@ -161,31 +170,32 @@ def unflatten_stacked(spec: FlatSpec, bufs: Sequence[jax.Array]) -> PyTree:
     return jax.tree.unflatten(spec.treedef, leaves)
 
 
-def with_pspecs(spec: FlatSpec, pspecs: Sequence[Any]) -> FlatSpec:
+def with_pspecs(spec: FlatSpec, pspecs: Sequence[Any], mesh) -> FlatSpec:
     """Attach one ``PartitionSpec`` per dtype group (see
-    :func:`repro.sharding.specs.flat_group_pspecs`).  The spec stays a
-    static trace-time constant — the pspec rides along exactly like
-    ``rows`` so every consumer of the group buffers (engines, codecs,
-    checkpointing) can recover the intended placement."""
+    :func:`repro.sharding.specs.flat_group_pspecs`) and the mesh they
+    name.  The spec stays a static trace-time constant — the pspec rides
+    along exactly like ``rows`` so every consumer of the group buffers
+    (engines, codecs, checkpointing) can recover the intended placement,
+    and the server update can run its kernels per device over it."""
     assert len(pspecs) == len(spec.groups), (len(pspecs), len(spec.groups))
-    return FlatSpec(treedef=spec.treedef, groups=tuple(
+    return FlatSpec(treedef=spec.treedef, mesh=mesh, groups=tuple(
         dataclasses.replace(g, pspec=p)
         for g, p in zip(spec.groups, pspecs)))
 
 
-def constrain_groups(spec: FlatSpec, bufs: Sequence[jax.Array],
-                     mesh=None) -> List[jax.Array]:
+def constrain_groups(spec: FlatSpec,
+                     bufs: Sequence[jax.Array]) -> List[jax.Array]:
     """Apply each group's ``pspec`` as a ``with_sharding_constraint`` so
     GSPMD keeps the aggregate buffers partitioned (a no-op for groups
-    without a pspec, or when no mesh is known)."""
-    if mesh is None:
+    without a pspec, or when the spec names no mesh)."""
+    if spec.mesh is None:
         return list(bufs)
     from jax.sharding import NamedSharding
     out = []
     for g, b in zip(spec.groups, bufs):
         if g.pspec is not None:
             b = jax.lax.with_sharding_constraint(
-                b, NamedSharding(mesh, g.pspec))
+                b, NamedSharding(spec.mesh, g.pspec))
         out.append(b)
     return out
 

@@ -30,10 +30,11 @@ __all__ = ["sanitize_errors", "check_flat_groups", "checkify_round",
 #     and it is the probe whose message names the aggregation buffer and
 #     the recovery path; --sanitize turns on jax_debug_nans to localize
 #     genesis instead;
-#   * index_checks — jax 0.4.37's checkify rule for scatter (the transpose
-#     of gather under autodiff, produced by every take_along_axis-style
-#     loss) raises `IndexError: tuple index out of range` at trace time;
-#     re-add `checkify.index_checks` here once jax is bumped past that bug.
+#   * index_checks — checkify's rule for scatter (the transpose of gather
+#     under autodiff, produced by every take_along_axis-style loss) raises
+#     `IndexError: tuple index out of range` at trace time, still on jax
+#     0.9.0 (checkify of jax.grad over a take_along_axis loss reproduces
+#     it); re-add `checkify.index_checks` here once that rule is fixed.
 sanitize_errors = checkify.user_checks
 
 

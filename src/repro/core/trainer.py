@@ -59,6 +59,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.checkpoint import CheckpointManager
 from repro.checkpoint import restore as ckpt_restore
@@ -341,10 +342,16 @@ class FederatedTrainer:
         window and the phase spans (analysis time is recorded in the
         event, not smeared into the measured phases)."""
         from repro.roofline.live import round_roofline_event
-        absargs = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
-                                           jnp.result_type(x)),
-            (self.state, *staged))
+        def abstract(x):
+            # a mesh placement (the sharded executor's state) is part of
+            # the program; a default-device one is left unspecified, as the
+            # dispatch leaves it, so both compile the same program once
+            sh = getattr(x, "sharding", None)
+            return jax.ShapeDtypeStruct(
+                jnp.shape(x), jnp.result_type(x),
+                sharding=sh if isinstance(sh, NamedSharding) else None)
+
+        absargs = jax.tree.map(abstract, (self.state, *staged))
         # sanitize-mode rounds are checkify closures without .lower —
         # round_roofline_event returns None and the event is skipped
         self._roofline_events[k] = round_roofline_event(

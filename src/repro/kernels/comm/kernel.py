@@ -168,8 +168,8 @@ def sign_pack_pass(g: jax.Array, mu, n_valid: int, *,
         functools.partial(_sign_pack_kernel, with_error=with_error,
                           rows_block=br),
         grid=(rows // br,),
-        in_specs=[_scalar_spec(1, interpret),
-                  pl.BlockSpec((1, 1), lambda i: (0, 0)), tile],
+        in_specs=[_scalar_spec(1, interpret), _scalar_spec(1, interpret),
+                  tile],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
@@ -206,8 +206,8 @@ def sign_unpack_fma_pass(acc: jax.Array, packed: jax.Array, mu_w,
     return pl.pallas_call(
         functools.partial(_sign_unpack_fma_kernel, rows_block=br),
         grid=(rows // br,),
-        in_specs=[_scalar_spec(1, interpret),
-                  pl.BlockSpec((1, 1), lambda i: (0, 0)), tile, pack_tile],
+        in_specs=[_scalar_spec(1, interpret), _scalar_spec(1, interpret),
+                  tile, pack_tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
         interpret=interpret,

@@ -26,12 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU memory spaces (ANY/VMEM); interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-    VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 

@@ -61,11 +61,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU memory spaces; interpret mode works without them
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.flat import LANES
 
@@ -75,7 +71,8 @@ N_SCALARS = 4
 
 def _block_rows(rows: int, target: int = 256) -> int:
     """Largest power-of-two row tile <= target that divides ``rows``
-    (rows is a multiple of 8 by construction of FlatSpec)."""
+    (FlatSpec rows are a multiple of ``flat.ROW_ALIGN``, so the default
+    target always divides them)."""
     br = min(target, rows)
     while rows % br:
         br //= 2
@@ -83,9 +80,10 @@ def _block_rows(rows: int, target: int = 256) -> int:
 
 
 def _scalar_spec(cols: int, interpret: bool):
-    """(1, cols) scalar-operand placement: SMEM on real TPUs, default
-    memory in interpret mode (where pltpu may be unavailable)."""
-    if pltpu is not None and not interpret:
+    """(1, cols) scalar operand or accumulator placement: SMEM on real
+    TPUs (Mosaic refuses scalar stores to VMEM), default memory in
+    interpret mode."""
+    if not interpret:
         return pl.BlockSpec((1, cols), lambda i: (0, 0),
                             memory_space=pltpu.SMEM)
     return pl.BlockSpec((1, cols), lambda i: (0, 0))
@@ -125,7 +123,7 @@ def aggregate_pass(g_stack: jax.Array, w_norm: jax.Array, *,
         ],
         out_specs=[
             pl.BlockSpec((br, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            _scalar_spec(1, interpret),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
@@ -192,7 +190,7 @@ def accumulate_pass_bwd(g: jax.Array, w, d_out: jax.Array, *,
         _accumulate_bwd_kernel,
         grid=(rows // br,),
         in_specs=[w_spec, tile, tile],
-        out_specs=[tile, pl.BlockSpec((1, 1), lambda i: (0, 0))],
+        out_specs=[tile, _scalar_spec(1, interpret)],
         out_shape=[
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
@@ -307,7 +305,7 @@ def aggregate_pass_bwd(g_stack: jax.Array, w_norm: jax.Array, G: jax.Array,
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((cohort, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            _scalar_spec(1, interpret),
             pl.BlockSpec((cohort, br, LANES), lambda i: (0, i, 0)),
             pl.BlockSpec((br, LANES), lambda i: (i, 0)),
             pl.BlockSpec((br, LANES), lambda i: (i, 0)),
@@ -421,10 +419,7 @@ def update_pass_bwd(G: jax.Array, m: Optional[jax.Array],
     assert lanes == LANES, G.shape
     br = _block_rows(rows, block_rows)
     tile = pl.BlockSpec((br, LANES), lambda i: (i, 0))
-    # same SMEM placement as the forward's scalar operand; the (1, 4)
-    # cotangent OUTPUT stays in VMEM like the forward's (1, 1) ssq
-    scal_in = _scalar_spec(N_SCALARS, interpret)
-    scal_out = pl.BlockSpec((1, N_SCALARS), lambda i: (0, 0))
+    scal_spec = _scalar_spec(N_SCALARS, interpret)
     buf = jax.ShapeDtypeStruct((rows, LANES), jnp.float32)
     scal_buf = jax.ShapeDtypeStruct((1, N_SCALARS), jnp.float32)
 
@@ -438,8 +433,8 @@ def update_pass_bwd(G: jax.Array, m: Optional[jax.Array],
     outs = pl.pallas_call(
         kernel,
         grid=(rows // br,),
-        in_specs=[scal_in] + [tile] * (1 + n_state + len(ct_in)),
-        out_specs=[tile] * (1 + n_state) + [scal_out],
+        in_specs=[scal_spec] + [tile] * (1 + n_state + len(ct_in)),
+        out_specs=[tile] * (1 + n_state) + [scal_spec],
         out_shape=[buf] * (1 + n_state) + [scal_buf],
         interpret=interpret,
     )(scalars.astype(jnp.float32), G, *state_in, *ct_in)

@@ -41,6 +41,7 @@ from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import flat as flat_mod
 from repro.core.flat import FlatSpec, make_flat_spec
@@ -301,8 +302,17 @@ def _apply_groups(spec: FlatSpec, Gs, gn, params: PyTree, opt_state: PyTree,
     ms = opt_state.get("m", (None,) * len(spec.groups))
     vs = opt_state.get("v", (None,) * len(spec.groups))
     new_p, new_m, new_v = [], [], []
-    for G, p, m, v in zip(Gs, p_groups, ms, vs):
-        np_, nm, nv = upd(G, p, m, v, scalars)
+    for g, G, p, m, v in zip(spec.groups, Gs, p_groups, ms, vs):
+        fn = upd
+        if spec.mesh is not None:
+            # Mosaic kernels are not partitioned automatically: run the
+            # update on each device's block of the group (it is
+            # elementwise given the replicated scalars)
+            b = g.pspec if g.pspec is not None else P()
+            fn = jax.shard_map(upd, mesh=spec.mesh,
+                               in_specs=(b, b, b, b, P()),
+                               out_specs=(b, b, b), check_vma=False)
+        np_, nm, nv = fn(G, p, m, v, scalars)
         new_p.append(np_)
         new_m.append(nm)
         new_v.append(nv)

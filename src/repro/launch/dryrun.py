@@ -229,8 +229,6 @@ def run_one(arch_name: str, shape_name: str, *, multi_pod: bool = False,
     if s["memory"]:
         rec["memory"] = s["memory"]
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # jax 0.4.x: list of one dict
-        cost = cost[0] if cost else {}
     rec["cost"] = {k: float(v) for k, v in cost.items()
                    if isinstance(v, (int, float)) and (
                        "flops" in k or "bytes" in k or "utilization" not in k)}

@@ -5,16 +5,10 @@ from __future__ import annotations
 import math
 
 import jax
-
-try:  # jax >= 0.5: explicit axis types (Auto == the pre-0.5 behavior)
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - older jax: every axis is Auto
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes, devices):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes, devices=devices)
     return jax.make_mesh(shape, axes, devices=devices,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -24,13 +24,16 @@ Usage (CPU-scale example):
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import importlib
 import json
 import os
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.comm import available_codecs
 from repro.configs import FedConfig, get_arch
@@ -62,7 +65,8 @@ def build_synthetic_fed_data(cfg, *, num_clients: int, examples: int,
 
 
 def run_training(arch: str, *, rounds: int, cohort: int, client_batch: int,
-                 seq: int, algorithm: str = "uga", meta: bool = True,
+                 seq: int, layers: Optional[int] = None,
+                 algorithm: str = "uga", meta: bool = True,
                  share: bool = False, local_steps: int = 2,
                  local_epochs: int = 1, client_lr: float = 0.01,
                  server_lr: Optional[float] = None,
@@ -111,12 +115,16 @@ def run_training(arch: str, *, rounds: int, cohort: int, client_batch: int,
     busy/gap, per-phase attribution) when the window closes;
     ``roofline`` emits a ``roofline`` event per compiled round program
     (trip-count-aware predicted cost + measured rounds/s — inspect with
-    ``python -m repro.roofline.report <run_dir>``).  With a
+    ``python -m repro.roofline.report <run_dir>``).  ``layers``: keep
+    only the model's first ``layers`` blocks, every width unchanged (a
+    depth cut, for runs that must compile quickly).  With a
     ``run_dir``, the trainer keeps a
     managed checkpoint store in ``run_dir/checkpoints`` (a save every
     ``ckpt_every`` rounds — 0: once at run end — with ``keep_last`` /
     ``keep_every`` retention)."""
     cfg = get_arch(arch)
+    if layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
     model = build_model(cfg, dtype=dtype, loss_chunk=256)
     fed = FedConfig(
         algorithm=algorithm, meta=meta, share=share, cohort=cohort,
@@ -141,17 +149,15 @@ def run_training(arch: str, *, rounds: int, cohort: int, client_batch: int,
         # catch NaNs in UNsanitized code too (jit deoptimizes and re-checks
         # on a NaN output); the checkify probes stay the primary, named
         # diagnostics — debug_nans is the coarse backstop
-        import jax
         jax.config.update("jax_debug_nans", True)
     data = build_synthetic_fed_data(cfg, num_clients=num_clients,
                                     examples=examples, seq=seq, iid=iid,
                                     seed=seed)
-    round_kwargs = {}
+    round_kwargs, mesh = {}, None
     if executor == "sharded":
         # two-tier aggregation over every visible device: the cohort axis
         # splits across the mesh data axis, each shard streams its clients
         # through the chunked core, one psum reduces the partials
-        import jax
         from repro.launch.mesh import make_auto_mesh
         from repro.sharding.specs import cohort_grad_shardings
         mesh = make_auto_mesh(mesh_model)
@@ -184,6 +190,12 @@ def run_training(arch: str, *, rounds: int, cohort: int, client_batch: int,
         extra = trainer.restore(resume)
         print(f"[train] resumed {resume} at round {trainer.round} "
               f"(saved by arch={extra.get('arch')})")
+    if mesh is not None:
+        # the round program returns the state replicated over the mesh;
+        # start it there, so round 0 compiles the program every later
+        # round runs
+        trainer.state = jax.device_put(
+            trainer.state, NamedSharding(mesh, PartitionSpec()))
     meta_bs = min(client_batch * 2, 32)
     history = trainer.run(data, rounds=rounds, cohort=cohort,
                           batch=client_batch, meta_batch=meta_bs,
@@ -371,6 +383,8 @@ def main():
                     help=">0: re-enqueue failed clients after "
                          "backoff * 2^attempt rounds")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     state, history = run_training(
         args.arch, rounds=args.rounds, cohort=args.cohort,
         client_batch=args.client_batch, seq=args.seq,

@@ -66,12 +66,17 @@ VECTOR_METRICS: FrozenSet[str] = frozenset({"staleness_hist"})
 
 # one per compiled round program (trainer roofline=True): the trip-count-
 # aware cost model's per-round prediction (repro.roofline.live) plus the
-# measured rounds/s from the dispatch + device-sync spans
+# measured rounds/s from the dispatch + device-sync spans.  ``device`` is
+# the platform/kind the program was compiled for and ``peaks_kind`` the
+# PEAKS row the prediction divided by (a what-if off the TPU);
+# ``tpu_custom_calls`` counts the Mosaic kernel call sites, whose work the
+# cost model cannot see
 ROOFLINE_EVENT_KEYS: FrozenSet[str] = frozenset({
     "rounds_per_call", "flops_per_round", "bytes_per_round",
     "collective_bytes_per_round", "per_collective", "compute_s_per_round",
     "memory_s_per_round", "collective_s_per_round", "bottleneck",
     "predicted_rounds_per_s", "loop_ratio", "xla_flops", "memory",
+    "device", "peaks_kind", "tpu_custom_calls", "compile_s",
     "analysis_s", "measured_rounds_per_s", "measured_s_per_round",
     "rounds_measured"})
 

@@ -11,18 +11,49 @@ parsed from ``compiled.as_text()`` are per-chip quantities:
 (equivalent to the global formulation HLO_FLOPs / (chips * peak) since
 global = per_chip * chips for an SPMD program).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (values fixed by the assignment).
+The peaks come from :data:`PEAKS`, keyed by ``jax.Device.device_kind``.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks of one accelerator kind."""
+    flops: float             # dense bf16 FLOP/s per chip
+    hbm_bw: float            # HBM bytes/s per chip
+    link_bw: float           # bytes/s per chip-to-chip link
+    source: str
+
+
+# One row per device kind; a TPU kind missing here is an error, never a
+# default (see peaks_for).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip "
+               "interconnect over 4 links (50 GB/s each)"),
+}
+# the kind whose peaks a run off the TPU borrows, as a what-if
+WHATIF_KIND = "TPU v5 lite"
+
+
+def peaks_for(platform: str, device_kind: str) -> Tuple[str, Peaks]:
+    """(kind whose peaks apply, its peaks) for a device.  A TPU kind with
+    no row in :data:`PEAKS` raises; any other platform gets the
+    :data:`WHATIF_KIND` row, and the caller records that it did."""
+    if platform != "tpu":
+        return WHATIF_KIND, PEAKS[WHATIF_KIND]
+    if device_kind not in PEAKS:
+        raise ValueError(
+            f"no roofline peaks for device kind {device_kind!r}; add its "
+            f"published row to repro.roofline.analysis.PEAKS (known: "
+            f"{sorted(PEAKS)})")
+    return device_kind, PEAKS[device_kind]
 
 COLLECTIVE_OPS = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                   "collective-permute")
@@ -102,10 +133,11 @@ class Roofline:
 def roofline_terms(flops_per_chip: float, bytes_per_chip: float,
                    coll_bytes_per_chip: float,
                    model_flops_global: Optional[float] = None,
-                   chips: int = 256) -> Roofline:
-    c = flops_per_chip / PEAK_FLOPS
-    m = bytes_per_chip / HBM_BW
-    n = coll_bytes_per_chip / LINK_BW
+                   chips: int = 256,
+                   peaks: Peaks = PEAKS[WHATIF_KIND]) -> Roofline:
+    c = flops_per_chip / peaks.flops
+    m = bytes_per_chip / peaks.hbm_bw
+    n = coll_bytes_per_chip / peaks.link_bw
     terms = {"compute": c, "memory": m, "collective": n}
     bottleneck = max(terms, key=terms.get)
     ratio = None

@@ -13,12 +13,13 @@ program it is actually dispatching:
   * :func:`round_roofline_event` — one ``roofline`` tracker-event
     payload per compiled round program: per-round FLOPs/bytes/collective
     bytes and the predicted compute/memory/collective seconds + rounds/s
-    under the TPU-v5e hardware model (``roofline.analysis`` constants).
-    The trainer appends the *measured* rounds/s from its dispatch +
-    device-sync spans before emitting, so prediction and measurement sit
-    in the same ``metrics.jsonl`` line.  On other backends (CI runs on
-    CPU) the prediction stays a v5e what-if; the measured fields are the
-    ground truth.
+    under the device's row of ``roofline.analysis.PEAKS`` (a TPU kind
+    without a row raises).  The trainer appends the *measured* rounds/s
+    from its dispatch + device-sync spans before emitting, so prediction
+    and measurement sit in the same ``metrics.jsonl`` line.  Off the TPU
+    (CI runs on CPU) the prediction is a what-if under the
+    ``WHATIF_KIND`` row, and the event names both that kind and the
+    device it ran on.
 
 Event keys are pinned by ``repro.obs.schema.ROOFLINE_EVENT_KEYS``.
 """
@@ -27,7 +28,10 @@ from __future__ import annotations
 import time
 from typing import Any, Dict, Optional
 
-from repro.roofline.analysis import parse_collectives, roofline_terms
+import jax
+
+from repro.roofline.analysis import (parse_collectives, peaks_for,
+                                     roofline_terms)
 from repro.roofline.hlo_cost import analyze as hlo_analyze
 
 __all__ = ["compiled_cost_summary", "round_roofline_event"]
@@ -45,8 +49,6 @@ def compiled_cost_summary(compiled) -> Dict[str, Any]:
     the FLOPs correction ratio (same loop structure), keeping
     fusion-level granularity — the convention dryrun.py established."""
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):      # jax 0.4.x: list of one dict
-        cost = cost[0] if cost else {}
     xla_flops = float(cost.get("flops", 0.0))
     xla_bytes = float(cost.get("bytes accessed", 0.0))
     hlo = compiled.as_text()
@@ -73,6 +75,7 @@ def compiled_cost_summary(compiled) -> Dict[str, Any]:
         "loop_ratio": loop_ratio,
         "bytes_est": xla_bytes * max(loop_ratio, 1.0),
         "memory": memory,
+        "tpu_custom_calls": hlo.count('custom_call_target="tpu_custom_call"'),
     }
 
 
@@ -85,11 +88,14 @@ def round_roofline_event(jitted_fn, args, *, rounds_per_call: int = 1
     lower = getattr(jitted_fn, "lower", None)
     if lower is None:
         return None
+    dev = jax.devices()[0]
+    peaks_kind, peaks = peaks_for(dev.platform, dev.device_kind)
     t0 = time.perf_counter()
     compiled = lower(*args).compile()
+    compile_s = time.perf_counter() - t0
     s = compiled_cost_summary(compiled)
     rl = roofline_terms(s["hlo_flops"], s["bytes_est"],
-                        s["collective_bytes"])
+                        s["collective_bytes"], peaks=peaks)
     k = max(int(rounds_per_call), 1)
     t_round = max(rl.compute_s, rl.memory_s, rl.collective_s) / k
     return {
@@ -106,5 +112,9 @@ def round_roofline_event(jitted_fn, args, *, rounds_per_call: int = 1
         "loop_ratio": s["loop_ratio"],
         "xla_flops": s["xla_flops"],
         "memory": s["memory"],
+        "device": {"platform": dev.platform, "kind": dev.device_kind},
+        "peaks_kind": peaks_kind,
+        "tpu_custom_calls": s["tpu_custom_calls"],
+        "compile_s": compile_s,
         "analysis_s": round(time.perf_counter() - t0, 4),
     }

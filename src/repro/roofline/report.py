@@ -45,9 +45,11 @@ def main(argv: Optional[List[str]] = None) -> int:
         return 1
     for ev in rooflines:
         k = ev.get("rounds_per_call", 1)
+        dev = ev.get("device") or {}
         print(f"roofline: rounds_per_call={k} "
               f"bottleneck={ev.get('bottleneck')} "
-              f"(TPU-v5e hardware model)")
+              f"(ran on {dev.get('platform')}/{dev.get('kind')}, peaks of "
+              f"{ev.get('peaks_kind')})")
         print(f"  per-round cost     flops={_g(ev.get('flops_per_round'))} "
               f"bytes={_g(ev.get('bytes_per_round'))} "
               f"collective={_g(ev.get('collective_bytes_per_round'))}")
@@ -68,7 +70,9 @@ def main(argv: Optional[List[str]] = None) -> int:
                   + " ".join(f"{a}={_g(v)}" for a, v in sorted(pc.items())))
         print(f"  loop_ratio={_g(ev.get('loop_ratio'))} "
               f"xla_flops={_g(ev.get('xla_flops'))} "
-              f"analysis_s={_g(ev.get('analysis_s'))}")
+              f"compile_s={_g(ev.get('compile_s'))} "
+              f"analysis_s={_g(ev.get('analysis_s'))} "
+              f"tpu_custom_calls={ev.get('tpu_custom_calls')}")
     return 0
 
 

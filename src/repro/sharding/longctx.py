@@ -31,16 +31,11 @@ def sharded_flash_decode(q, k_cache, v_cache, index, *, mesh: Mesh,
         m, l, o = flash_decode_partial(q, k, v, index, shard * loc)
         return combine_partials(m, l, o, axis)
 
-    if hasattr(jax, "shard_map"):           # jax >= 0.6
-        smap, check_kw = jax.shard_map, "check_vma"
-    else:                                   # jax 0.4.x
-        from jax.experimental.shard_map import shard_map as smap
-        check_kw = "check_rep"
-    fn = smap(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(None, axis, None, None), P(None, axis, None, None),
                   P()),
         out_specs=P(),
-        **{check_kw: False},
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, index).astype(v_cache.dtype)

@@ -73,7 +73,7 @@ def test_flat_roundtrip_structure_and_dtypes(key):
     spec = F.make_flat_spec(tree)
     assert len(spec.groups) == 2                     # float32 + bfloat16
     for g in spec.groups:
-        assert g.rows % 8 == 0 and g.rows * F.LANES >= g.size
+        assert g.rows % F.ROW_ALIGN == 0 and g.rows * F.LANES >= g.size
     rt = F.unflatten_tree(spec, F.flatten_tree(spec, tree))
     assert jax.tree_util.tree_structure(rt) == \
         jax.tree_util.tree_structure(tree)
@@ -176,11 +176,17 @@ def test_fused_round_matches_legacy_round(key, opt):
         fed = FedConfig(fused_update=fused, **kw)
         rf = jax.jit(make_federated_round(model, fed))
         st = init_server_state(model, fed, key)
+        p0 = st["params"]
         states[fused], metrics[fused] = rf(st, batch, meta, wts, key)
     for k in states[False]["params"]:
         a = np.asarray(states[True]["params"][k])
         b = np.asarray(states[False]["params"][k])
-        rel = np.max(np.abs(a - b) / (np.abs(b) + 1e-6))
+        p = np.asarray(p0[k])
+        # the new parameter is p0 - step, and adam's first step is about
+        # lr * sign(g) for every element, so an element whose p0 is close
+        # to its step cancels to near zero; the engines' rounding is
+        # relative to the operands of that subtraction, not its result
+        rel = np.max(np.abs(a - b) / (np.abs(p) + np.abs(p - b) + 1e-6))
         assert rel <= 1e-5, (opt, k, rel)
     for name in ("client_loss", "grad_norm", "meta_loss"):
         np.testing.assert_allclose(float(metrics[True][name]),

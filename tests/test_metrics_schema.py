@@ -170,10 +170,30 @@ def test_roofline_event_schema_matches_live_payload():
     assert set(ev) == set(ROOFLINE_EVENT_KEYS) - measured
     assert measured < ROOFLINE_EVENT_KEYS
     assert ev["rounds_per_call"] == 2
+    # the event names the device it compiled for and the peaks it used
+    dev = jax.devices()[0]
+    assert ev["device"] == {"platform": dev.platform,
+                            "kind": dev.device_kind}
+    if dev.platform != "tpu":
+        from repro.roofline import WHATIF_KIND
+        assert ev["peaks_kind"] == WHATIF_KIND
+    assert ev["tpu_custom_calls"] == 0          # no Pallas kernel in fn
 
     # a callable without .lower (sanitize-mode closure) is skipped, and
     # the skip is a None — not a crash, not a partial event
     assert round_roofline_event(lambda x: x, (1.0,)) is None
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    """A TPU kind without a published row is an error, never a default;
+    off the TPU the what-if row applies and says so."""
+    from repro.roofline import PEAKS, WHATIF_KIND, peaks_for
+    v5e = PEAKS["TPU v5 lite"]
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9) and v5e.source
+    assert peaks_for("tpu", "TPU v5 lite") == ("TPU v5 lite", v5e)
+    with pytest.raises(ValueError, match="no roofline peaks"):
+        peaks_for("tpu", "TPU v0 unknown")
+    assert peaks_for("cpu", "cpu") == (WHATIF_KIND, PEAKS[WHATIF_KIND])
 
 
 def test_profile_summary_event_schema_matches_summarizer():

@@ -213,6 +213,14 @@ def test_equivalence_matrix_bit_identical(key, fused, strat, mode, opt):
     assert tree_equal(st_new, st_ref)
     assert sorted(m_new) == sorted(m_ref)
     for name in m_new:
+        if name == "grad_norm":
+            # a metric only: the sum of squares under its sqrt is a
+            # reduction each separately compiled program may order its own
+            # way, so it agrees to fp32 rounding, not to the bit
+            np.testing.assert_allclose(np.asarray(m_new[name]),
+                                       np.asarray(m_ref[name]), rtol=1e-6,
+                                       err_msg=name)
+            continue
         np.testing.assert_array_equal(np.asarray(m_new[name]),
                                       np.asarray(m_ref[name]), err_msg=name)
 
@@ -350,12 +358,17 @@ def test_fednova_matches_fedavg_at_tau_server_lr(key):
         fed = FedConfig(algorithm=algo, meta=False, cohort=4, local_steps=2,
                         local_epochs=1, client_lr=0.05, server_lr=slr)
         st = init_server_state(model, fed, key)
+        p0 = st["params"]
         states[algo], _ = jax.jit(make_federated_round(model, fed))(
             st, batch, meta, wts, key)
-    for a, b in zip(jax.tree.leaves(states["fedavg"]["params"]),
-                    jax.tree.leaves(states["fednova"]["params"])):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-7)
+    for p, a, b in zip(jax.tree.leaves(p0),
+                       jax.tree.leaves(states["fedavg"]["params"]),
+                       jax.tree.leaves(states["fednova"]["params"])):
+        p, a, b = np.asarray(p), np.asarray(a), np.asarray(b)
+        # the ulp is that of the server step's operands (p0 and the step),
+        # not of the result, which cancels to near zero where they meet
+        bound = 1e-6 * (np.abs(p) + np.abs(p - b)) + 1e-7
+        assert np.all(np.abs(a - b) <= bound), np.max(np.abs(a - b) - bound)
 
 
 def test_fednova_registered_and_validates():
@@ -457,10 +470,16 @@ def test_participation_with_through_aggregation(key):
                     client_lr=0.05, server_lr=0.1, server_opt="sgd",
                     fused_update=True, meta_mode="through_aggregation",
                     ctrl_lr=1.0, participation=0.5)
-    mask = np.asarray(participation_mask(key, 4, 0.5))
+    # a round rng whose mask drops someone and keeps at least two: a sole
+    # survivor's normalized weight is 1 whatever its logit, so its
+    # hypergradient is exactly zero and the assertion below needs two
+    rng = next(k for k in map(jax.random.PRNGKey, range(64))
+               if 2 <= np.sum(participation_mask(k, 4, 0.5)) < 4)
+    mask = np.asarray(participation_mask(rng, 4, 0.5))
+    assert 2 <= mask.sum() < 4, mask
     st = init_server_state(model, fed, key)
     st, m = jax.jit(make_federated_round(model, fed))(
-        st, batch, meta, wts, key)
+        st, batch, meta, wts, rng)
     wl = np.asarray(st["ctrl"]["w_logits"])
     assert np.all(wl[mask == 0.0] == 0.0)
     assert np.any(wl[mask == 1.0] != 0.0)
@@ -646,6 +665,44 @@ def test_train_cli_plugin_flag_registers_algorithm(tmp_path):
         capture_output=True, text=True, cwd=root, env=env, timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "round    1" in out.stdout
+
+
+def test_run_training_layers_cuts_depth_only():
+    """``layers`` keeps the first N blocks of the arch and every width."""
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.launch.train import run_training
+    from repro.models.model import build_model
+    state, hist = run_training(
+        "smollm-360m-smoke", rounds=1, cohort=2, client_batch=2, seq=16,
+        layers=1, meta=False, num_clients=4, examples=32, log_every=0)
+    assert len(hist) == 1
+    cfg = get_arch("smollm-360m-smoke")
+    assert cfg.num_layers > 1
+    want = jax.eval_shape(
+        build_model(dataclasses.replace(cfg, num_layers=1),
+                    dtype=jnp.float32).init,
+        jax.random.PRNGKey(0))
+    got = jax.tree.map(lambda x: (x.shape, x.dtype), state["params"])
+    assert got == jax.tree.map(lambda x: (x.shape, x.dtype), want)
+
+
+def test_enable_compile_cache_env_or_fixed_path(monkeypatch, tmp_path):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says, untouched, and
+    otherwise to a fixed directory at the checkout root."""
+    from repro.launch import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cc.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cc.enable_compile_cache() == str(cc.CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cc.CACHE_DIR)
+        assert cc.CACHE_DIR.name == ".jax_cache"
+        assert (cc.CACHE_DIR.parent / "pytest.ini").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 def test_sample_round_cohort_exceeds_clients_actionable_error():
