@@ -71,18 +71,23 @@ def client_coded_accumulate(codec: GradientCodec, spec: FlatSpec,
     UNCHANGED: overwriting it would discard the decoded part of the error
     as if the server had received it, breaking the EF telescoping for
     every dropped round.
+
+    Traced under the ``repro.codec`` named scope (the scale reductions
+    beside the codec kernels carry it).
     """
     new_accs, new_res = [], []
-    if residuals is None:
-        for group, acc, g in zip(spec.groups, accs, g_bufs):
-            payload = codec.encode(group, g)
+    with jax.named_scope("repro.codec"):
+        if residuals is None:
+            for group, acc, g in zip(spec.groups, accs, g_bufs):
+                payload = codec.encode(group, g)
+                new_accs.append(codec.decode_fma(group, acc, payload, w))
+            return tuple(new_accs), None
+        transmitted = (jnp.asarray(w, jnp.float32) > 0.0
+                       ).astype(jnp.float32)
+        for group, acc, g, res in zip(spec.groups, accs, g_bufs, residuals):
+            payload, r_new = codec.encode_ef(group, g + res)
             new_accs.append(codec.decode_fma(group, acc, payload, w))
-        return tuple(new_accs), None
-    transmitted = (jnp.asarray(w, jnp.float32) > 0.0).astype(jnp.float32)
-    for group, acc, g, res in zip(spec.groups, accs, g_bufs, residuals):
-        payload, r_new = codec.encode_ef(group, g + res)
-        new_accs.append(codec.decode_fma(group, acc, payload, w))
-        new_res.append(transmitted * r_new + (1.0 - transmitted) * res)
+            new_res.append(transmitted * r_new + (1.0 - transmitted) * res)
     return tuple(new_accs), tuple(new_res)
 
 
@@ -97,7 +102,8 @@ def coded_aggregate_stacked(codec: GradientCodec, spec: FlatSpec,
     Returns (G_groups, new_residuals) — G_groups in the same layout
     ``repro.kernels.fused_update.ops.flat_weighted_aggregate`` produces
     (list of (rows, LANES) fp32), new_residuals stacked back to
-    (cohort, rows, LANES) per group (or None without error feedback)."""
+    (cohort, rows, LANES) per group (or None without error feedback).
+    Traced under the ``repro.codec`` named scope."""
     from repro.core import flat as flat_mod           # lazy: import cycle
     w = client_weights.astype(jnp.float32)
     w = w / jnp.maximum(jnp.sum(w), 1e-30)
@@ -108,8 +114,9 @@ def coded_aggregate_stacked(codec: GradientCodec, spec: FlatSpec,
                                               res_k)
         return accs, r_new
 
-    acc0 = tuple(flat_mod.zeros_flat(spec))
-    G, new_res = lax.scan(body, acc0, (tuple(g_groups), w, residuals))
+    with jax.named_scope("repro.codec"):
+        acc0 = tuple(flat_mod.zeros_flat(spec))
+        G, new_res = lax.scan(body, acc0, (tuple(g_groups), w, residuals))
     return list(G), new_res
 
 
@@ -131,7 +138,8 @@ def coded_decode_stacked(codec: GradientCodec, spec: FlatSpec,
     committed.
 
     Returns (decoded stacks — list of (cohort, rows, LANES) fp32 per dtype
-    group — and new_residuals stacked per group, or None without EF)."""
+    group — and new_residuals stacked per group, or None without EF).
+    Traced under the ``repro.codec`` named scope."""
     w = client_weights.astype(jnp.float32)
 
     def body(carry, xs):
@@ -149,5 +157,7 @@ def coded_decode_stacked(codec: GradientCodec, spec: FlatSpec,
             res_out.append(transmitted * r_new + (1.0 - transmitted) * res)
         return carry, (tuple(dec_k), tuple(res_out))
 
-    _, (dec, new_res) = lax.scan(body, (), (tuple(g_groups), w, residuals))
+    with jax.named_scope("repro.codec"):
+        _, (dec, new_res) = lax.scan(body, (),
+                                     (tuple(g_groups), w, residuals))
     return list(dec), new_res

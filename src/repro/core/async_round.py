@@ -51,12 +51,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.algorithms import get_algorithm
 from repro.core.engines import resolve_engine
 from repro.core.executors import FlatAggregate, get_executor
 from repro.core.flat import LANES, FlatSpec, make_flat_spec, zeros_flat
 from repro.core.meta import meta_update
-from repro.core.round import participation_mask, resolve_server_lr
+from repro.core.round import (build_client_update, participation_mask,
+                              resolve_server_lr)
 from repro.core.sanitize import check_flat_groups
 from repro.kernels.fused_update.ops import flat_accumulate
 from repro.models.model import Model
@@ -116,12 +116,7 @@ def make_async_tick(model: Model, fed, *, algorithm: Optional[str] = None,
     rng) -> (state, metrics)`` — same signature as the synchronous
     ``one_round``, so ``rounds_per_call`` chunking, the trainer and the
     checkpoint layout all reuse unchanged."""
-    alg = get_algorithm(algorithm if algorithm is not None
-                        else fed.algorithm)
-    client_update = alg.build(
-        model.loss, local_steps=fed.local_steps,
-        local_epochs=fed.local_epochs, prox_mu=fed.prox_mu,
-        remat=fed.remat_local_steps)
+    client_update = build_client_update(model, fed, algorithm)
     if executor not in (None, "buffered_async"):
         raise ValueError(
             f"engine='buffered_async' runs its own delta-pooling executor; "
@@ -266,8 +261,9 @@ def make_async_tick(model: Model, fed, *, algorithm: Optional[str] = None,
 
             accs, _ = lax.scan(acc_body, tuple(zeros_flat(spec)), (pool, wn))
             handle = FlatAggregate(list(accs), spec, sq_norm=None)
-            new_p, new_o, gn = eng.apply(params_f, handle, opt_f,
-                                         lr=server_lr)
+            with jax.named_scope("repro.server"):
+                new_p, new_o, gn = eng.apply(params_f, handle, opt_f,
+                                             lr=server_lr)
 
             s_sel = jnp.where(sel, s, 0.0)
             bins = jnp.clip(s.astype(jnp.int32), 0, STALENESS_HIST_BINS - 1)

@@ -12,6 +12,10 @@ buffer directly; the zero pad
 is mathematically inert for every supported optimizer (0-gradient => 0
 update) and is dropped again by :func:`unflatten_tree`.
 
+The four (un)flatten functions trace under the ``repro.flatten`` named
+scope, so their concatenate / pad / slice ops carry it in the compiled
+program and in a profiler trace.
+
 Round-trip contract (property-tested): ``unflatten_tree(spec,
 flatten_tree(spec, tree))`` preserves structure, shapes and dtypes, with
 values equal up to the fp32 cast the legacy tree-map path performs anyway.
@@ -114,10 +118,11 @@ def flatten_tree(spec: FlatSpec, tree: PyTree) -> List[jax.Array]:
     :func:`flatten_stacked` never materializes."""
     leaves = jax.tree.leaves(tree)
     out = []
-    for g in spec.groups:
-        parts = [leaves[l.index].astype(jnp.float32).reshape(l.size)
-                 for l in g.leaves]
-        out.append(_pack(parts, g.size, g.rows))
+    with jax.named_scope("repro.flatten"):
+        for g in spec.groups:
+            parts = [leaves[l.index].astype(jnp.float32).reshape(l.size)
+                     for l in g.leaves]
+            out.append(_pack(parts, g.size, g.rows))
     return out
 
 
@@ -127,10 +132,11 @@ def flatten_stacked(spec: FlatSpec, tree: PyTree) -> List[jax.Array]:
     leaves = jax.tree.leaves(tree)
     cohort = leaves[0].shape[0]
     out = []
-    for g in spec.groups:
-        parts = [leaves[l.index].astype(jnp.float32).reshape(cohort, l.size)
-                 for l in g.leaves]
-        out.append(_pack(parts, g.size, g.rows, lead=(cohort,)))
+    with jax.named_scope("repro.flatten"):
+        for g in spec.groups:
+            parts = [leaves[l.index].astype(jnp.float32).reshape(
+                cohort, l.size) for l in g.leaves]
+            out.append(_pack(parts, g.size, g.rows, lead=(cohort,)))
     return out
 
 
@@ -143,12 +149,14 @@ def unflatten_tree(spec: FlatSpec, bufs: Sequence[jax.Array],
     the engine a tree in ``grad_agg_dtype`` (one cast, not a lossy
     fp32 -> leaf-dtype -> agg-dtype double hop)."""
     leaves: List[Any] = [None] * spec.num_leaves
-    for g, buf in zip(spec.groups, bufs):
-        flat = buf.reshape(g.rows * LANES)
-        for l in g.leaves:
-            x = jax.lax.slice(flat, (l.offset,), (l.offset + l.size,))
-            leaves[l.index] = x.reshape(l.shape).astype(
-                jnp.dtype(l.dtype) if dtype is None else jnp.dtype(dtype))
+    with jax.named_scope("repro.flatten"):
+        for g, buf in zip(spec.groups, bufs):
+            flat = buf.reshape(g.rows * LANES)
+            for l in g.leaves:
+                x = jax.lax.slice(flat, (l.offset,), (l.offset + l.size,))
+                leaves[l.index] = x.reshape(l.shape).astype(
+                    jnp.dtype(l.dtype) if dtype is None
+                    else jnp.dtype(dtype))
     return jax.tree.unflatten(spec.treedef, leaves)
 
 
@@ -160,13 +168,15 @@ def unflatten_stacked(spec: FlatSpec, bufs: Sequence[jax.Array]) -> PyTree:
     sits at buffer level), but it is the tool for offline inspection of
     per-client cotangents in model coordinates."""
     leaves: List[Any] = [None] * spec.num_leaves
-    for g, buf in zip(spec.groups, bufs):
-        cohort = buf.shape[0]
-        flat = buf.reshape(cohort, g.rows * LANES)
-        for l in g.leaves:
-            x = jax.lax.slice(flat, (0, l.offset), (cohort, l.offset + l.size))
-            leaves[l.index] = x.reshape((cohort,) + l.shape).astype(
-                jnp.dtype(l.dtype))
+    with jax.named_scope("repro.flatten"):
+        for g, buf in zip(spec.groups, bufs):
+            cohort = buf.shape[0]
+            flat = buf.reshape(cohort, g.rows * LANES)
+            for l in g.leaves:
+                x = jax.lax.slice(flat, (0, l.offset),
+                                  (cohort, l.offset + l.size))
+                leaves[l.index] = x.reshape((cohort,) + l.shape).astype(
+                    jnp.dtype(l.dtype))
     return jax.tree.unflatten(spec.treedef, leaves)
 
 

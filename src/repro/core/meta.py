@@ -47,17 +47,20 @@ PyTree = Any
 
 def meta_update(loss_fn: Callable, params: PyTree, meta_batch: PyTree,
                 meta_lr, rng=None) -> Tuple[PyTree, jax.Array]:
-    """w <- w - eta_meta * grad L(w; D_meta).  Returns (params, meta_loss)."""
+    """w <- w - eta_meta * grad L(w; D_meta).  Returns (params, meta_loss).
+    Traced under the ``repro.meta`` named scope."""
 
     def obj(w):
         l, _ = loss_fn(w, meta_batch, rng)
         return l
 
-    meta_loss, g = jax.value_and_grad(obj)(params)
-    new = jax.tree.map(
-        lambda p, gi: (p.astype(jnp.float32)
-                       - meta_lr * gi.astype(jnp.float32)).astype(p.dtype),
-        params, g)
+    with jax.named_scope("repro.meta"):
+        meta_loss, g = jax.value_and_grad(obj)(params)
+        new = jax.tree.map(
+            lambda p, gi: (p.astype(jnp.float32)
+                           - meta_lr * gi.astype(jnp.float32)
+                           ).astype(p.dtype),
+            params, g)
     return new, meta_loss
 
 
@@ -80,21 +83,25 @@ def meta_update_through_cohort(
     hypergradients updates the controllable state.
 
     Returns (new_params, new_opt_state, grad_norm_after_clip, client_loss,
-    new_ctrl, metrics)."""
+    new_ctrl, metrics).  Traced under the ``repro.meta`` named scope; the
+    clients' updates it runs nest their ``repro.client`` scope inside, and
+    the server step its ``repro.server`` scope."""
 
     def objective(w_logits, log_lr):
         eff_w = client_weights.astype(jnp.float32) * jnp.exp(w_logits)
         handle, client_loss = reweightable.aggregate(eff_w)
-        new_p, new_opt, gn = engine.apply(params, handle, opt_state,
-                                          lr=jnp.exp(log_lr))
+        with jax.named_scope("repro.server"):
+            new_p, new_opt, gn = engine.apply(params, handle, opt_state,
+                                              lr=jnp.exp(log_lr))
         l, _ = loss_fn(new_p, meta_batch, rng)
         return l, (new_p, new_opt, gn, client_loss)
 
-    (meta_loss, (new_p, new_opt, gn, client_loss)), (d_wl, d_llr) = \
-        jax.value_and_grad(objective, argnums=(0, 1), has_aux=True)(
-            ctrl["w_logits"], ctrl["log_lr"])
-    new_ctrl = {"w_logits": ctrl["w_logits"] - ctrl_lr * d_wl,
-                "log_lr": ctrl["log_lr"] - ctrl_lr * d_llr}
+    with jax.named_scope("repro.meta"):
+        (meta_loss, (new_p, new_opt, gn, client_loss)), (d_wl, d_llr) = \
+            jax.value_and_grad(objective, argnums=(0, 1), has_aux=True)(
+                ctrl["w_logits"], ctrl["log_lr"])
+        new_ctrl = {"w_logits": ctrl["w_logits"] - ctrl_lr * d_wl,
+                    "log_lr": ctrl["log_lr"] - ctrl_lr * d_llr}
     metrics = {"meta_loss": meta_loss,
                "ctrl_w_gnorm": jnp.sqrt(jnp.sum(d_wl * d_wl)),
                "ctrl_lr_grad": d_llr,

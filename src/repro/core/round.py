@@ -130,6 +130,27 @@ def participation_mask(rng: jax.Array, cohort: int, rate: float) -> jax.Array:
     return keep.astype(jnp.float32)
 
 
+def build_client_update(model: Model, fed: FedConfig,
+                        algorithm: Optional[str] = None):
+    """The registered local update (``fed.algorithm``, or ``algorithm``)
+    for ``model``, traced under the ``repro.client`` named scope: every
+    executor runs it, so the ops of the clients' local steps, gradient
+    evaluations and Hessian-vector products carry the scope in the
+    compiled program and in a profiler trace."""
+    alg = get_algorithm(algorithm if algorithm is not None
+                        else fed.algorithm)
+    local_update = alg.build(
+        model.loss, local_steps=fed.local_steps,
+        local_epochs=fed.local_epochs, prox_mu=fed.prox_mu,
+        remat=fed.remat_local_steps)
+
+    def client_update(*args):
+        with jax.named_scope("repro.client"):
+            return local_update(*args)
+
+    return client_update
+
+
 def make_federated_round(model: Model, fed: FedConfig, *,
                          spmd_axis_name=None, grad_shardings=None,
                          rounds_per_call: int = 1,
@@ -187,12 +208,7 @@ def make_federated_round(model: Model, fed: FedConfig, *,
         # the sync barrier simply cannot observe payload corruption
         faults = dataclasses.replace(faults, garble=0.0)
 
-    alg = get_algorithm(algorithm if algorithm is not None
-                        else fed.algorithm)
-    client_update = alg.build(
-        model.loss, local_steps=fed.local_steps,
-        local_epochs=fed.local_epochs, prox_mu=fed.prox_mu,
-        remat=fed.remat_local_steps)
+    client_update = build_client_update(model, fed, algorithm)
     exe = resolve_executor(fed, spmd_axis_name=spmd_axis_name,
                            grad_shardings=grad_shardings, executor=executor)
     eng = eng_probe
@@ -321,8 +337,6 @@ def make_federated_round(model: Model, fed: FedConfig, *,
             handle, client_loss, new_comm = exe.run_coded(
                 client_update, params, cohort_batch, client_weights, lr_c,
                 rng_c, codec=codec, comm=state.get("comm"))
-            new_params, opt_state, gn_post = eng.apply(
-                params, handle, state["opt"], lr=server_lr)
             # measured uplink bytes: per-client payload size (static — the
             # codec's transport shapes) times the clients that reported
             bytes_pc = comm_bytes_per_client(codec, make_flat_spec(params))
@@ -333,8 +347,10 @@ def make_federated_round(model: Model, fed: FedConfig, *,
             handle, client_loss = exe.run(
                 client_update, params, cohort_batch, client_weights, lr_c,
                 rng_c, kind=kind)
-            new_params, opt_state, gn_post = eng.apply(
-                params, handle, state["opt"], lr=server_lr)
+        if not through_agg:
+            with jax.named_scope("repro.server"):
+                new_params, opt_state, gn_post = eng.apply(
+                    params, handle, state["opt"], lr=server_lr)
 
         # one metrics assembly for every arm: rounds_per_call chunking
         # (lax.scan) needs identical keys per config, so the executor/

@@ -29,9 +29,12 @@ host once per chunk), and returns one record per round
 Observability (``repro.obs``): every record is fed to the trainer's
 :class:`~repro.obs.MetricsTracker` (``tracker=`` — a registry name,
 instance, or comma list; default ``noop``), each chunk's host phases
-(``sample_stack`` / ``dispatch`` / ``device_sync`` / ``checkpoint``) are
-emitted as ``phase`` events, and ``profile=N`` captures a JAX trace for
-rounds ``[profile_start, profile_start+N)`` into ``run_dir/profile``.
+(``sample_stack`` with ``stage`` nested in it / ``dispatch`` /
+``device_sync`` / ``checkpoint``) are emitted as ``phase`` events and, in
+a capture, as ``repro.phase.*`` trace annotations, a dispatch that traced
+or compiled a program emits a ``compile`` event, and ``profile=N``
+captures a JAX trace for rounds ``[profile_start, profile_start+N)`` into
+``run_dir/profile``.
 The analysis layer rides on top: ``trace_summary=True`` parses the
 closed capture into a ``profile_summary`` event (top ops by self time,
 busy/gap, per-phase attribution — ``repro.obs.trace_analysis``) and
@@ -52,7 +55,6 @@ passes through to :func:`repro.core.round.make_federated_round`.
 """
 from __future__ import annotations
 
-import contextlib
 import os
 from typing import Any, Callable, Dict, List, Optional
 
@@ -110,10 +112,12 @@ class FederatedTrainer:
         self._retry_attempts: Dict[int, int] = {}
         self._retry_due: Dict[int, List[int]] = {}
         # ---- observability ------------------------------------------------
+        from repro.obs.compiles import compile_counter
         from repro.obs.profiler import RoundProfiler
         from repro.obs.trackers import resolve_tracker
         self.run_dir = run_dir
         self.tracker = resolve_tracker(tracker, run_dir=run_dir)
+        self._compiles = compile_counter()
         self.profiler = RoundProfiler(run_dir, start=profile_start,
                                       rounds=profile, tracker=self.tracker)
         # ---- analysis layer (PR 10) ---------------------------------------
@@ -264,17 +268,32 @@ class FederatedTrainer:
                                            meta_batch, samples[j])
                          for j in range(k)]
                 rngs = [round_key(self.key, r + j) for j in range(k)]
-                staged = self._stage_inputs(samples, metas, rngs)
+                with span(trk, "stage", round=r, k=k):
+                    staged = self._stage_inputs(samples, metas, rngs)
             if self._roofline and k not in self._roofline_events:
                 # before dispatch: staged buffers may be donated by the
                 # round program; the abstract shapes must be read first
                 self._prepare_roofline(k, staged)
-            self.profiler.maybe_start(r, k)
-            with span(trk, "dispatch", round=r, k=k) as sp_d, \
-                    self._phase_annotation("dispatch"):
+            # The capture opens here, after the first traced chunk's
+            # sample_stack span, and closes right after its last chunk's
+            # device_sync span, before anything else is annotated: a
+            # capture's phase annotations then start at its first dispatch
+            # and end at its last device sync, which is the traced window
+            # the benchmark reads.  Keep that order.
+            # the compile event covers the dispatch and the profiler's read
+            # of the program it is about to run
+            compiles0 = self._compiles.reading()
+            self.profiler.maybe_start(r, k,
+                                      program=self._program_lowering(k,
+                                                                     staged))
+            with span(trk, "dispatch", round=r, k=k) as sp_d:
                 metrics = self._dispatch(k, staged)
-            with span(trk, "device_sync", round=r, k=k) as sp_s, \
-                    self._phase_annotation("device_sync"):
+            compiles1 = self._compiles.reading()
+            if compiles1 != compiles0:
+                trk.log_event("compile", {
+                    "round": r, "k": k, "n": compiles1[0] - compiles0[0],
+                    "dur_s": compiles1[1] - compiles0[1]})
+            with span(trk, "device_sync", round=r, k=k) as sp_s:
                 # the dispatch span above measures enqueue time only (jax
                 # dispatch is async); this one is the actual device work
                 # left to drain — together they expose the overlap
@@ -319,14 +338,15 @@ class FederatedTrainer:
         return run_history
 
     # ---- analysis-layer hooks (PR 10) -------------------------------------
-    def _phase_annotation(self, name: str):
-        """The trace twin of the ``span()`` event: while the profiler is
-        capturing, wrap the phase in a ``repro.phase.<name>``
-        TraceAnnotation so ``obs/trace_analysis`` can attribute device
-        op self-time to phases.  A no-op context outside the window."""
-        if self.profiler.active:
-            return jax.profiler.TraceAnnotation(f"repro.phase.{name}")
-        return contextlib.nullcontext()
+    def _program_lowering(self, k: int, staged) -> Optional[Callable]:
+        """What the profiler calls to read the chunk program it captures:
+        the program lowered from this chunk's state and staged inputs,
+        before they are donated.  Sanitize-mode rounds are checkify
+        closures without ``lower`` and give none."""
+        fn = self._cache(k)
+        if not hasattr(fn, "lower"):
+            return None
+        return lambda: fn.lower(self.state, *staged)
 
     def _emit_trace_summary(self, trk) -> None:
         if not self._trace_summary:
@@ -417,7 +437,8 @@ class FederatedTrainer:
     def _stage_inputs(self, samples, metas, rngs):
         """Host-side staging (device transfer for k == 1, the
         ``stack_round_inputs`` chunk stack for k > 1) — split from
-        dispatch so the ``sample_stack`` phase span covers it."""
+        sampling and dispatch so the ``stage`` phase span, nested in
+        ``sample_stack``, covers it alone."""
         k = len(samples)
         if k == 1:
             return (jax.tree.map(jnp.asarray, samples[0]["cohort_batch"]),

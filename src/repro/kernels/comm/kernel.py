@@ -78,6 +78,7 @@ def quantize_i8_pass(g: jax.Array, inv_scale, scale, *,
                          jnp.asarray(scale, jnp.float32)]).reshape(1, 2)
     outs = pl.pallas_call(
         functools.partial(_quantize_i8_kernel, with_error=with_error),
+        name="quantize_i8_pass",
         grid=(rows // br,),
         in_specs=[_scalar_spec(2, interpret), tile],
         out_specs=out_specs,
@@ -103,6 +104,7 @@ def dequant_i8_fma_pass(acc: jax.Array, q: jax.Array, scale_w, *,
     tile = pl.BlockSpec((br, LANES), lambda i: (i, 0))
     return pl.pallas_call(
         _dequant_i8_fma_kernel,
+        name="dequant_i8_fma_pass",
         grid=(rows // br,),
         in_specs=[_scalar_spec(1, interpret), tile, tile],
         out_specs=tile,
@@ -167,6 +169,7 @@ def sign_pack_pass(g: jax.Array, mu, n_valid: int, *,
     outs = pl.pallas_call(
         functools.partial(_sign_pack_kernel, with_error=with_error,
                           rows_block=br),
+        name="sign_pack_pass",
         grid=(rows // br,),
         in_specs=[_scalar_spec(1, interpret), _scalar_spec(1, interpret),
                   tile],
@@ -205,6 +208,7 @@ def sign_unpack_fma_pass(acc: jax.Array, packed: jax.Array, mu_w,
     pack_tile = pl.BlockSpec((br // SIGN_PACK, LANES), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_sign_unpack_fma_kernel, rows_block=br),
+        name="sign_unpack_fma_pass",
         grid=(rows // br,),
         in_specs=[_scalar_spec(1, interpret), _scalar_spec(1, interpret),
                   tile, pack_tile],

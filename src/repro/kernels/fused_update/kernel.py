@@ -116,6 +116,7 @@ def aggregate_pass(g_stack: jax.Array, w_norm: jax.Array, *,
     br = _block_rows(rows, block_rows)
     G, ssq = pl.pallas_call(
         _aggregate_kernel,
+        name="aggregate_pass",
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((cohort, 1), lambda i: (0, 0)),
@@ -154,6 +155,7 @@ def accumulate_pass(acc: jax.Array, g: jax.Array, w, *,
     w_spec = _scalar_spec(1, interpret)
     out = pl.pallas_call(
         _accumulate_kernel,
+        name="accumulate_pass",
         grid=(rows // br,),
         in_specs=[w_spec, tile, tile],
         out_specs=tile,
@@ -188,6 +190,7 @@ def accumulate_pass_bwd(g: jax.Array, w, d_out: jax.Array, *,
     w_spec = _scalar_spec(1, interpret)
     dg, dw = pl.pallas_call(
         _accumulate_bwd_kernel,
+        name="accumulate_pass_bwd",
         grid=(rows // br,),
         in_specs=[w_spec, tile, tile],
         out_specs=[tile, _scalar_spec(1, interpret)],
@@ -258,6 +261,7 @@ def update_pass(G: jax.Array, p: jax.Array, m: Optional[jax.Array],
                                b1=b1, b2=b2, eps=eps)
     outs = pl.pallas_call(
         kernel,
+        name="update_pass",
         grid=(rows // br,),
         in_specs=[scal_spec] + [tile] * (2 + len(state_in)),
         out_specs=[tile] * n_out,
@@ -302,6 +306,7 @@ def aggregate_pass_bwd(g_stack: jax.Array, w_norm: jax.Array, G: jax.Array,
     br = _block_rows(rows, block_rows)
     dg, dw = pl.pallas_call(
         _aggregate_bwd_kernel,
+        name="aggregate_pass_bwd",
         grid=(rows // br,),
         in_specs=[
             pl.BlockSpec((cohort, 1), lambda i: (0, 0)),
@@ -432,6 +437,7 @@ def update_pass_bwd(G: jax.Array, m: Optional[jax.Array],
                                b1=b1, b2=b2, eps=eps)
     outs = pl.pallas_call(
         kernel,
+        name="update_pass_bwd",
         grid=(rows // br,),
         in_specs=[scal_spec] + [tile] * (1 + n_state + len(ct_in)),
         out_specs=[tile] * (1 + n_state) + [scal_spec],

@@ -279,13 +279,15 @@ flash_attention.defvjp(_fa_fwd, _fa_bwd)
 def attend(q, k, v, *, causal: bool = True, window: int = 0,
            q_block: int = 512, kv_block: int = 512):
     """Dispatch: flash (custom-vjp) path when shapes are block-divisible,
-    else the plain chunked scan (small smoke shapes)."""
+    else the plain chunked scan (small smoke shapes).  Traced under the
+    ``repro.attention`` named scope, forward and backward."""
     qb = min(q_block, q.shape[1])
     kb = min(kv_block, k.shape[1])
-    if q.shape[1] % qb == 0 and k.shape[1] % kb == 0:
-        return flash_attention(q, k, v, causal, window, qb, kb)
-    return chunked_attention(q, k, v, causal=causal, window=window,
-                             q_block=qb, kv_block=kb)
+    with jax.named_scope("repro.attention"):
+        if q.shape[1] % qb == 0 and k.shape[1] % kb == 0:
+            return flash_attention(q, k, v, causal, window, qb, kb)
+        return chunked_attention(q, k, v, causal=causal, window=window,
+                                 q_block=qb, kv_block=kb)
 
 
 def simple_attention(q, k, v, *, causal: bool = True, window: int = 0,

@@ -5,8 +5,10 @@
 (``noop`` / ``console`` / ``jsonl`` / ``csv`` / ``composite`` built in,
 ``tensorboard`` behind an optional-dependency gate,
 :func:`register_tracker` for plugins), host-side phase :func:`span`
-timing, the :class:`RoundProfiler` capturing a JAX trace for a round
-window, and the documented round-metrics schema
+timing (each span also a ``repro.phase.<name>`` trace annotation), the
+:class:`RoundProfiler` capturing a JAX trace for a round window, the
+process-wide :func:`compile_counter` behind the trainer's ``compile``
+events, and the documented round-metrics schema
 (:func:`round_metric_keys`).
 
 On top of that substrate sits the analysis layer (PR 10): trace
@@ -19,6 +21,7 @@ Wired through ``FederatedTrainer(tracker=..., run_dir=...,
 trace_summary=..., roofline=...)`` and ``train.py --tracker/--run-dir/
 --profile/--trace-summary/--roofline``.
 """
+from repro.obs.compiles import CompileCounter, compile_counter
 from repro.obs.profiler import RoundProfiler
 from repro.obs.regress import (Tolerances, compare_bench_files,
                                compare_run_dirs, summarize_run)
@@ -37,7 +40,8 @@ __all__ = ["MetricsTracker", "NoopTracker", "ConsoleTracker",
            "JsonlTracker", "CsvTracker", "CompositeTracker",
            "TensorBoardTracker", "register_tracker", "get_tracker",
            "available_trackers", "resolve_tracker", "span",
-           "RoundProfiler", "round_metric_keys", "VECTOR_METRICS",
+           "RoundProfiler", "CompileCounter", "compile_counter",
+           "round_metric_keys", "VECTOR_METRICS",
            "ROOFLINE_EVENT_KEYS", "PROFILE_SUMMARY_EVENT_KEYS",
            "summarize_trace", "find_trace_file", "emit_profile_summary",
            "summarize_run", "compare_run_dirs", "compare_bench_files",

@@ -9,10 +9,10 @@ actually needs:
     (pid, tid) lane — aggregated by op name into a top-K table;
   * **busy vs gap** time: the union of op intervals vs the op stream's
     wall window (a growing gap = dispatch stalls, not slower kernels);
-  * **per-phase attribution**: while the capture is open the trainer
-    wraps dispatch / device-sync in
+  * **per-phase attribution**: while the capture is open every
+    ``span()`` of the trainer is also a
     ``jax.profiler.TraceAnnotation("repro.phase.<name>")`` (the trace
-    twin of the tracker's ``span()`` events), so each op's self time is
+    twin of the tracker's ``phase`` events), so each op's self time is
     credited to the phase window(s) overlapping it.  Ops outside every
     window — e.g. compilation running inside the capture — land in
     ``_unattributed`` rather than disappearing.
@@ -35,8 +35,8 @@ __all__ = ["PHASE_PREFIX", "load_trace", "find_trace_file", "op_events",
            "phase_windows", "self_times", "interval_union_us", "summarize",
            "summarize_trace", "emit_profile_summary"]
 
-# TraceAnnotation prefix the trainer uses while the profiler is active;
-# the suffix is the span() phase name (dispatch / device_sync)
+# TraceAnnotation prefix of every span() while the profiler is active;
+# the suffix is the phase name (sample_stack, stage, dispatch, ...)
 PHASE_PREFIX = "repro.phase."
 
 

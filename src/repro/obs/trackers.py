@@ -46,11 +46,14 @@ Register alternatives (a wandb/tensorboard bridge, a socket shipper) with
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import csv as _csv
 import json
 import os
 import time
 from typing import Any, Callable, Dict, Iterable, Optional, Sequence
+
+import jax
 
 from repro.core.registry import Registry
 
@@ -146,25 +149,45 @@ def _require_run_dir(run_dir: Optional[str], tracker: str, artifact: str
     return run_dir
 
 
+# the phase of the innermost open span (a span's ``parent``)
+_OPEN_SPAN: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_open_span", default=None)
+
+
 @contextlib.contextmanager
 def span(tracker: MetricsTracker, phase: str, **data):
     """Wall-clock span emitted as a ``phase`` tracker event — the
     round-phase profiler's host-side half.  The trainer wraps each chunk's
-    sample/stack, dispatch, device-sync (``block_until_ready``) and
-    checkpoint stages so async-dispatch-vs-compute overlap is visible in
-    the event stream (a long ``device_sync`` next to a short ``dispatch``
-    IS the overlap).
+    sample/stack (with its ``stage`` span, the hand-over of the sampled
+    inputs to the runtime, nested inside), dispatch, device-sync
+    (``block_until_ready``) and checkpoint stages so async-dispatch-vs-
+    compute overlap is visible in the event stream (a long ``device_sync``
+    next to a short ``dispatch`` IS the overlap).
+
+    The span is also a ``jax.profiler.TraceAnnotation`` named
+    ``repro.phase.<phase>`` carrying the ``round`` and ``parent`` args, so
+    a profiler capture holds the same span on the trace's own clock (the
+    annotation records nothing while no capture runs).  The event carries
+    ``parent``: the phase of the span it is nested in, or None.
 
     Yields a dict that carries ``dur_s`` after the block exits, so the
     caller can read the measured duration back without re-timing (the
     trainer's measured-rounds/s accounting for the roofline event)."""
     info = dict(data)
+    parent = _OPEN_SPAN.get()
+    args = {"round": info["round"]} if "round" in info else {}
+    if parent is not None:
+        args["parent"] = parent
+    token = _OPEN_SPAN.set(phase)
     t0 = time.perf_counter()
     try:
-        yield info
+        with jax.profiler.TraceAnnotation(f"repro.phase.{phase}", **args):
+            yield info
     finally:
         info["dur_s"] = time.perf_counter() - t0
-        tracker.log_event("phase", {"phase": phase, **info})
+        _OPEN_SPAN.reset(token)
+        tracker.log_event("phase", {"phase": phase, "parent": parent,
+                                    **info})
 
 
 # ---------------------------------------------------------------------------

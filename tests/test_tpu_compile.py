@@ -9,6 +9,7 @@ process at a time may load the TPU library, and the test workers all
 import this module.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -104,13 +105,56 @@ KERNELS = {
 }
 
 
+def kernel_bodies(hlo_text: str) -> dict:
+    """{custom-call instruction name: the words of its kernel's serialized
+    module}, read by the benchmark's own trace library."""
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from lib.trace import kernels_from_hlo
+    return kernels_from_hlo(hlo_text)
+
+
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(shapes, name):
     fn, operands = KERNELS[name]
     compiled = jax.jit(fn).lower(*(shapes[o] for o in operands)).compile()
+    text = compiled.as_text()
     # Mosaic compiled the kernel: it is a custom call, not an interpreted
     # loop of XLA ops
-    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    # the call carries its wrapper's name, and its body the kernel
+    # function's name
+    wrapper = name.split("[")[0]
+    (instr, words), = kernel_bodies(text).items()
+    assert re.fullmatch(re.escape(wrapper) + r"(\.\d+)?", instr), instr
+    assert "_" + wrapper.replace("_pass", "") + "_kernel" in words.split()
+
+
+def test_kernel_families_found_in_v5e_program(shapes):
+    """One program holding the accumulate, int8 quantize and dequant-FMA
+    and Adam update kernels: the benchmark's HLO reading finds each family
+    by its kernel function's name, under the call's stable name."""
+    def prog(acc, g, w, m, v, scal):
+        acc = FK.accumulate_pass(acc, g, w)
+        q, _ = CK.quantize_i8_pass(g, w, w, with_error=True)
+        acc = CK.dequant_i8_fma_pass(acc, q, w)
+        return FK.update_pass(acc, g, m, v, scal, opt="adam")
+
+    text = jax.jit(prog).lower(
+        shapes["buf"], shapes["buf"], shapes["scalar"], shapes["buf"],
+        shapes["buf"], shapes["scal4"]).compile().as_text()
+    found = {}
+    for instr, words in kernel_bodies(text).items():
+        for fam in ("accumulate", "quantize_i8", "dequant_i8_fma", "update"):
+            if f"_{fam}_kernel" in words.split():
+                found[fam] = instr
+    assert sorted(found) == ["accumulate", "dequant_i8_fma", "quantize_i8",
+                             "update"]
+    for fam, instr in found.items():
+        assert instr.startswith(f"{fam}_pass"), (fam, instr)
 
 
 def test_sharded_server_update_compiles_for_v5e_mesh(topo):
