@@ -22,7 +22,10 @@ strategy, where the per-client gradients are never stacked:
     gradient into the group accumulator, ``acc + w_k * g_k``, in a single
     HBM sweep.  The scan carry is the flat buffer itself, so a scan round
     is K streaming accumulates plus the same :func:`update_pass` — no
-    pytree-carry tree-maps, no flatten round-trip of the aggregate.
+    pytree-carry tree-maps, no flatten round-trip of the aggregate.  The
+    output aliases ``acc`` (``input_output_aliases``), so a loop-carried
+    accumulator is updated in place: without the alias XLA copies the
+    whole carry before every call, since the call still reads it.
 
 Backward kernels give each pair a hand-written VJP (wired up by the
 ``jax.custom_vjp`` ops in ``ops.py``) so meta-learning *through* the
@@ -147,7 +150,13 @@ def accumulate_pass(acc: jax.Array, g: jax.Array, w, *,
                     ) -> jax.Array:
     """acc/g: (rows, LANES) fp32; w: scalar normalized client weight.
     Returns ``acc + w * g`` — the per-client streaming Eq. (14) term the
-    scan strategy carries instead of a pytree."""
+    scan strategy carries instead of a pytree.
+
+    The result is written into ``acc``'s buffer.  Semantics are unchanged:
+    where the caller still uses ``acc`` after the call (outside a jit, or
+    a value read again later in the program), XLA copies ``acc`` first and
+    the caller pays one full-buffer copy; where ``acc`` dies at the call,
+    as a scan carry does, no copy is made."""
     rows, lanes = acc.shape
     assert lanes == LANES, acc.shape
     br = _block_rows(rows, block_rows)
@@ -160,6 +169,7 @@ def accumulate_pass(acc: jax.Array, g: jax.Array, w, *,
         in_specs=[w_spec, tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+        input_output_aliases={1: 0},
         interpret=interpret,
     )(jnp.asarray(w, jnp.float32).reshape(1, 1), acc, g)
     return out

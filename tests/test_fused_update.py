@@ -416,6 +416,62 @@ def test_accumulate_pass_matches_formula_and_vjp(key, use_ref):
     assert_grads_close(got_g, want_g)
 
 
+# the accumulate formula as XLA computes it, with no kernel and no alias
+fma = jax.jit(lambda a, g, w: a + w * g)
+
+
+@pytest.mark.parametrize("jit", [False, True])
+def test_accumulate_pass_keeps_a_live_accumulator(jit):
+    """The kernel writes into ``acc``'s buffer; a caller that keeps ``acc``
+    alive still reads it unchanged (XLA copies it first), and both calls on
+    the one ``acc`` give exactly ``acc + w * g``."""
+    rng = np.random.default_rng(5)
+    acc, g1, g2 = (jnp.asarray(rng.normal(0, 1, (16, F.LANES)), jnp.float32)
+                   for _ in range(3))
+    acc_before = np.asarray(acc).copy()
+    w1, w2 = jnp.float32(0.37), jnp.float32(-1.25)
+
+    def two(a, x, y):
+        return (K.accumulate_pass(a, x, w1, interpret=True),
+                K.accumulate_pass(a, y, w2, interpret=True), a)
+
+    out1, out2, acc_in = (jax.jit(two) if jit else two)(acc, g1, g2)
+    np.testing.assert_array_equal(np.asarray(acc_in), acc_before)
+    np.testing.assert_array_equal(np.asarray(acc), acc_before)
+    np.testing.assert_array_equal(np.asarray(out1),
+                                  np.asarray(fma(acc, g1, w1)))
+    np.testing.assert_array_equal(np.asarray(out2),
+                                  np.asarray(fma(acc, g2, w2)))
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+def test_streamed_aggregate_bitmatches_folded_formula(key, chunk):
+    """The streaming core's aggregate (accumulator updated in place) ==
+    ``acc + w_k * g_k`` folded over the per-client flat gradients in client
+    order, bit for bit; chunk 3 of a cohort of 5 is the ragged case."""
+    from repro.core.aggregate import (chunked_cohort_gradient_flat,
+                                      scan_cohort_deltas_flat)
+    model = make_mlp_model()
+    params = model.init(key)
+    spec = F.make_flat_spec(params)
+    rng = np.random.default_rng(6)
+    batch = sample_batch(rng, cohort=5, b=16)
+    wts = jnp.asarray(rng.uniform(1.0, 5.0, 5), jnp.float32)
+    cu = make_client_update("uga", model.loss, local_steps=2)
+
+    G, _ = jax.jit(lambda p: chunked_cohort_gradient_flat(
+        cu, p, batch, wts, 0.05, key, spec=spec, chunk=chunk))(params)
+    deltas, _ = jax.jit(lambda p: scan_cohort_deltas_flat(
+        cu, p, batch, wts, 0.05, key, spec=spec))(params)
+    wn = wts / jnp.maximum(jnp.sum(wts), 1e-30)
+    want = F.zeros_flat(spec)
+    for k in range(5):
+        want = [fma(a, d[k], wn[k]) for a, d in zip(want, deltas)]
+    assert len(G) == len(want)
+    for a, b in zip(G, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("use_ref", [False, True])
 @pytest.mark.parametrize("algo", ["uga", "fedavg"])
 def test_scan_flat_cohort_bitmatches_legacy_carry(key, use_ref, algo):

@@ -157,6 +157,57 @@ def test_kernel_families_found_in_v5e_program(shapes):
         assert instr.startswith(f"{fam}_pass"), (fam, instr)
 
 
+@pytest.mark.parametrize("caller", ["chunked", "through_aggregation"])
+def test_streaming_core_accumulates_in_place_on_v5e(topo, shapes, caller):
+    """The streaming cohort core at smollm-360m's flat width (chunk 1, a
+    cohort of 4, a stand-in client gradient built from the parameters), and
+    the gradient w.r.t. the client weights through its scan form: the
+    accumulate call writes its output into its accumulator operand, so XLA
+    copies no (rows, 128) fp32 accumulator per client."""
+    from jax.sharding import SingleDeviceSharding
+    from repro.core.aggregate import (chunked_cohort_gradient_flat,
+                                      scan_cohort_gradient_flat)
+
+    one = SingleDeviceSharding(topo.devices[0])
+    model = build_model(get_arch("smollm-360m"), dtype=jnp.float32)
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    spec = make_flat_spec(params)
+    batch = {"s": jax.ShapeDtypeStruct((COHORT,), jnp.float32, sharding=one)}
+
+    def client(w, b, lr, rng):
+        # each leaf's first element, broadcast: a whole-leaf product would
+        # cost minutes of compile for the same accumulate loop
+        return jax.tree.map(lambda p: jnp.broadcast_to(
+            p.reshape(-1)[0] * b["s"], p.shape), w), b["s"]
+
+    def chunked(p, b, wts):
+        return chunked_cohort_gradient_flat(client, p, b, wts, 0.01, None,
+                                            spec=spec, chunk=1,
+                                            interpret=False)
+
+    def through_aggregation(p, b, wts):
+        def meta_loss(wts):
+            G, loss = scan_cohort_gradient_flat(client, p, b, wts, 0.01,
+                                                None, spec=spec,
+                                                interpret=False)
+            return jnp.sum(G[0] * G[0]) + loss
+        return jax.grad(meta_loss)(wts)
+
+    fn = {"chunked": chunked, "through_aggregation": through_aggregation}
+    text = jax.jit(fn[caller]).lower(params, batch,
+                                     shapes["w"]).compile().as_text()
+    rows = shapes["rows"]
+    copies = re.findall(rf"f32\[{rows},128\]\{{[^}}]*\}} copy\(", text)
+    assert copies == []
+    calls = [line for line in text.splitlines()
+             if re.match(r"\s*%?accumulate_pass(\.\d+)? = ", line)]
+    assert len(calls) == 1
+    # operands (w, acc, g): the output is operand 1's buffer
+    assert "output_to_operand_aliasing={{}: (1, {})}" in calls[0]
+
+
 def test_sharded_server_update_compiles_for_v5e_mesh(topo):
     """The sharded executor's server step over a (4, 1) mesh of the
     described chips: Mosaic kernels are not partitioned automatically, so
