@@ -61,7 +61,9 @@ def client_coded_accumulate(codec: GradientCodec, spec: FlatSpec,
     error-feedback memory or None.  Returns (new_accs, new_residuals).
 
     The decode always fuses straight into the aggregate FMA
-    (``decode_fma`` — e.g. the int8 ``dequant_i8_fma_pass``); with EF the
+    (``decode_fma`` — e.g. the int8 ``dequant_i8_fma_pass``), which writes
+    into ``acc``'s buffer: a loop-carried ``acc`` is updated in place, and
+    a caller that reads ``acc`` again pays XLA's copy; with EF the
     encode additionally emits the residual in its own sweep
     (``encode_ef``), so EF costs no extra HBM pass over the plain path.
 

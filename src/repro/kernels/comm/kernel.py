@@ -13,12 +13,16 @@ stage is ONE HBM sweep, mirroring the ``kernels/fused_update`` structure:
     the scan cohort strategy: ``acc + (scale * w_k) * q`` — the int8
     analogue of ``fused_update.accumulate_pass`` (scale and the normalized
     client weight fold into ONE scalar, so decode costs nothing extra).
+    The output aliases ``acc`` (``input_output_aliases``), so a
+    loop-carried accumulator is updated in place; a caller that still
+    reads ``acc`` after the call pays one full-buffer copy, made by XLA.
   * :func:`sign_pack_pass` — signSGD-style 1-bit pack: 8 consecutive rows
     of sign bits pack into one uint8 row ``(rows // 8, LANES)``; with
     ``with_error=True`` also emits ``g - mu * sign(g)`` (valid elements
     only — see the padding note below).
   * :func:`sign_unpack_fma_pass` — unpack + decode + FMA in one sweep:
-    ``acc + (mu * w_k) * sign``.
+    ``acc + (mu * w_k) * sign``.  Its output aliases ``acc`` too, with
+    the same cost for a caller that keeps ``acc``.
 
 Padding note: the flat layout zero-pads each group to a row multiple.  For
 int8 the pad is self-inert (g = 0 -> q = 0 -> decode 0), but a sign bit
@@ -97,7 +101,13 @@ def dequant_i8_fma_pass(acc: jax.Array, q: jax.Array, scale_w, *,
                         ) -> jax.Array:
     """Streaming decode+accumulate: ``acc + scale_w * q`` with
     ``scale_w = scale * w_k`` folded into one scalar — the codec analogue
-    of ``fused_update.accumulate_pass``."""
+    of ``fused_update.accumulate_pass``.
+
+    The result is written into ``acc``'s buffer, as in ``accumulate_pass``.
+    Semantics are unchanged: where the caller still uses ``acc`` after the
+    call (outside a jit, or a value read again later in the program), XLA
+    copies ``acc`` first and the caller pays one full-buffer copy; where
+    ``acc`` dies at the call, as a scan carry does, no copy is made."""
     rows, lanes = acc.shape
     assert lanes == LANES, acc.shape
     br = _block_rows(rows, block_rows)
@@ -109,6 +119,7 @@ def dequant_i8_fma_pass(acc: jax.Array, q: jax.Array, scale_w, *,
         in_specs=[_scalar_spec(1, interpret), tile, tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+        input_output_aliases={1: 0},
         interpret=interpret,
     )(jnp.asarray(scale_w, jnp.float32).reshape(1, 1), acc, q)
 
@@ -197,7 +208,12 @@ def sign_unpack_fma_pass(acc: jax.Array, packed: jax.Array, mu_w,
                          interpret: bool = False) -> jax.Array:
     """Unpack + decode + streaming FMA: ``acc + mu_w * sign`` with
     ``mu_w = mu * w_k`` folded into one scalar; packed-pad elements
-    (flat index >= n_valid) contribute exact zeros."""
+    (flat index >= n_valid) contribute exact zeros.
+
+    Like :func:`dequant_i8_fma_pass`, the result is written into ``acc``'s
+    buffer: a caller that still uses ``acc`` after the call pays one
+    full-buffer copy, made by XLA; a scan carry or a temporary (the fresh
+    zeros of ``Sign1BitCodec.decode``) pays none."""
     rows, lanes = acc.shape
     assert lanes == LANES and rows % SIGN_PACK == 0, acc.shape
     assert packed.shape == (rows // SIGN_PACK, LANES), packed.shape
@@ -214,6 +230,7 @@ def sign_unpack_fma_pass(acc: jax.Array, packed: jax.Array, mu_w,
                   tile, pack_tile],
         out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+        input_output_aliases={2: 0},
         interpret=interpret,
     )(jnp.asarray(mu_w, jnp.float32).reshape(1, 1),
       jnp.asarray(n_valid, jnp.int32).reshape(1, 1), acc, packed)

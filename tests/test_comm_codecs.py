@@ -106,6 +106,100 @@ def test_sign_unpack_fma_kernel_matches_ref():
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
 
 
+def _payloads(seed):
+    """Two int8 payloads and two packed sign payloads of fresh gradients."""
+    out = []
+    for s in (seed, seed + 1):
+        g = rand_group(s)
+        scale = float(jnp.max(jnp.abs(g))) / 127.0
+        out.append((R.quantize_i8_ref(g, 1.0 / scale, scale),
+                    R.sign_pack_ref(g, 1.0, GROUP.size)))
+    return out
+
+
+# kernel name -> (the kernel on (acc, payload, scalar), its ref oracle)
+DECODE_FMA = {
+    "dequant_i8_fma_pass": (
+        lambda a, p, s: K.dequant_i8_fma_pass(a, p[0], s, interpret=True),
+        jax.jit(lambda a, p, s: R.dequant_i8_fma_ref(a, p[0], s))),
+    "sign_unpack_fma_pass": (
+        lambda a, p, s: K.sign_unpack_fma_pass(a, p[1], s, GROUP.size,
+                                               interpret=True),
+        jax.jit(lambda a, p, s: R.sign_unpack_fma_ref(a, p[1], s,
+                                                      GROUP.size))),
+}
+
+
+@pytest.mark.parametrize("jit", [False, True])
+@pytest.mark.parametrize("kernel", sorted(DECODE_FMA))
+def test_decode_fma_keeps_a_live_accumulator(kernel, jit):
+    """The decode-FMA kernels write into ``acc``'s buffer; a caller that
+    keeps ``acc`` alive still reads it unchanged (XLA copies it first), and
+    both calls on the one ``acc`` equal the ref oracle exactly."""
+    fn, ref = DECODE_FMA[kernel]
+    acc = rand_group(11)
+    acc_before = np.asarray(acc).copy()
+    p1, p2 = _payloads(12)
+    s1, s2 = jnp.float32(0.37), jnp.float32(-1.25)
+
+    def two(a, x, y):
+        return fn(a, x, s1), fn(a, y, s2), a
+
+    out1, out2, acc_in = (jax.jit(two) if jit else two)(acc, p1, p2)
+    np.testing.assert_array_equal(np.asarray(acc_in), acc_before)
+    np.testing.assert_array_equal(np.asarray(acc), acc_before)
+    np.testing.assert_array_equal(np.asarray(out1),
+                                  np.asarray(ref(acc, p1, s1)))
+    np.testing.assert_array_equal(np.asarray(out2),
+                                  np.asarray(ref(acc, p2, s2)))
+
+
+@pytest.mark.parametrize("chunk", [1, 3])
+def test_coded_stream_bitmatches_folded_dequant_ref(key, chunk):
+    """The coded streaming core with the int8 uplink (the accumulator
+    updated in place by the dequant-FMA kernel) == each client's int8
+    payload folded through ``dequant_i8_fma_ref`` in client order, bit for
+    bit; chunk 3 of a cohort of 5 is the ragged case."""
+    from test_plugin_api import make_mlp_model, sample_batch
+
+    from repro.core.aggregate import (chunked_cohort_gradient_coded,
+                                      scan_cohort_deltas_flat)
+    from repro.core.client import make_client_update
+    from repro.core.flat import zeros_flat
+
+    model = make_mlp_model()
+    params = model.init(key)
+    spec = make_flat_spec(params)
+    rng = np.random.default_rng(7)
+    batch = sample_batch(rng, cohort=5, b=16)
+    wts = jnp.asarray(rng.uniform(1.0, 5.0, 5), jnp.float32)
+    cu = make_client_update("uga", model.loss, local_steps=2)
+    codec = Int8Codec()
+
+    # the weights enter as arguments: as constants of the program XLA
+    # folds their normalization at compile time, which may round apart
+    # from the same division made at run time
+    G, _, _ = jax.jit(lambda p, w: chunked_cohort_gradient_coded(
+        cu, p, batch, w, 0.05, key, spec=spec, chunk=chunk,
+        codec=codec))(params, wts)
+    deltas, _ = jax.jit(lambda p, w: scan_cohort_deltas_flat(
+        cu, p, batch, w, 0.05, key, spec=spec))(params, wts)
+    wn = wts / jnp.maximum(jnp.sum(wts), 1e-30)
+
+    @jax.jit
+    def fold(acc, d, w):
+        payload = codec.encode(GROUP, d)
+        return R.dequant_i8_fma_ref(acc, payload["q"],
+                                    payload["scale"] * w)
+
+    want = zeros_flat(spec)
+    for k in range(5):
+        want = [fold(a, d[k], wn[k]) for a, d in zip(want, deltas)]
+    assert len(G) == len(want)
+    for a, b in zip(G, want):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_sign_pack_unpack_roundtrip_and_pad_inert():
     """pack -> unpack recovers sign(g) * mu on the valid elements and EXACT
     zero on the layout pad (the invariant flat_sq_norm / opt slots / EF

@@ -157,24 +157,46 @@ def test_kernel_families_found_in_v5e_program(shapes):
         assert instr.startswith(f"{fam}_pass"), (fam, instr)
 
 
-@pytest.mark.parametrize("caller", ["chunked", "through_aggregation"])
+# caller -> (cohort, the accumulating kernel, its `acc` operand's index)
+IN_PLACE_CALLERS = {
+    "chunked": (COHORT, "accumulate_pass", 1),
+    "through_aggregation": (COHORT, "accumulate_pass", 1),
+    # the int8 cell's own cohort; operands (scale_w, acc, q)
+    "coded_int8": (16, "dequant_i8_fma_pass", 1),
+    # error feedback: 16 residual slots of the full buffer do not fit one
+    # v5e, so two clients
+    "coded_int8_ef": (2, "dequant_i8_fma_pass", 1),
+    # operands (mu_w, n_valid, acc, packed)
+    "coded_sign1bit": (4, "sign_unpack_fma_pass", 2),
+}
+
+
+@pytest.mark.parametrize("caller", list(IN_PLACE_CALLERS))
 def test_streaming_core_accumulates_in_place_on_v5e(topo, shapes, caller):
     """The streaming cohort core at smollm-360m's flat width (chunk 1, a
-    cohort of 4, a stand-in client gradient built from the parameters), and
-    the gradient w.r.t. the client weights through its scan form: the
-    accumulate call writes its output into its accumulator operand, so XLA
-    copies no (rows, 128) fp32 accumulator per client."""
+    stand-in client gradient built from the parameters), plain, through the
+    gradient w.r.t. the client weights of its scan form, and with each
+    lossy codec between client and accumulator: the accumulating call
+    writes its output into its accumulator operand, so XLA copies no
+    (rows, 128) fp32 accumulator per client."""
     from jax.sharding import SingleDeviceSharding
-    from repro.core.aggregate import (chunked_cohort_gradient_flat,
+    from repro.comm.codecs import Int8Codec, Sign1BitCodec
+    from repro.core.aggregate import (chunked_cohort_gradient_coded,
+                                      chunked_cohort_gradient_flat,
                                       scan_cohort_gradient_flat)
 
+    cohort, kernel, acc_operand = IN_PLACE_CALLERS[caller]
     one = SingleDeviceSharding(topo.devices[0])
     model = build_model(get_arch("smollm-360m"), dtype=jnp.float32)
     params = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
         jax.eval_shape(model.init, jax.random.PRNGKey(0)))
     spec = make_flat_spec(params)
-    batch = {"s": jax.ShapeDtypeStruct((COHORT,), jnp.float32, sharding=one)}
+    rows = shapes["rows"]
+    batch = {"s": jax.ShapeDtypeStruct((cohort,), jnp.float32, sharding=one)}
+    wts = jax.ShapeDtypeStruct((cohort,), jnp.float32, sharding=one)
+    residual = jax.ShapeDtypeStruct((cohort, rows, 128), jnp.float32,
+                                    sharding=one)
 
     def client(w, b, lr, rng):
         # each leaf's first element, broadcast: a whole-leaf product would
@@ -195,17 +217,29 @@ def test_streaming_core_accumulates_in_place_on_v5e(topo, shapes, caller):
             return jnp.sum(G[0] * G[0]) + loss
         return jax.grad(meta_loss)(wts)
 
-    fn = {"chunked": chunked, "through_aggregation": through_aggregation}
-    text = jax.jit(fn[caller]).lower(params, batch,
-                                     shapes["w"]).compile().as_text()
-    rows = shapes["rows"]
+    def coded(codec, residuals=()):
+        def fn(p, b, wts, *res):
+            return chunked_cohort_gradient_coded(
+                client, p, b, wts, 0.01, None, spec=spec, chunk=1,
+                codec=codec, residuals=res or None)
+        return fn, residuals
+
+    fn, extra = {
+        "chunked": (chunked, ()),
+        "through_aggregation": (through_aggregation, ()),
+        "coded_int8": coded(Int8Codec(interpret=False)),
+        "coded_int8_ef": coded(Int8Codec(interpret=False), (residual,)),
+        "coded_sign1bit": coded(Sign1BitCodec(interpret=False)),
+    }[caller]
+    text = jax.jit(fn).lower(params, batch, wts, *extra).compile().as_text()
     copies = re.findall(rf"f32\[{rows},128\]\{{[^}}]*\}} copy\(", text)
     assert copies == []
     calls = [line for line in text.splitlines()
-             if re.match(r"\s*%?accumulate_pass(\.\d+)? = ", line)]
+             if re.match(rf"\s*%?{kernel}(\.\d+)? = ", line)]
     assert len(calls) == 1
-    # operands (w, acc, g): the output is operand 1's buffer
-    assert "output_to_operand_aliasing={{}: (1, {})}" in calls[0]
+    # the output is the accumulator operand's buffer
+    assert (f"output_to_operand_aliasing={{{{}}: ({acc_operand}, {{}})}}"
+            in calls[0])
 
 
 def test_sharded_server_update_compiles_for_v5e_mesh(topo):
