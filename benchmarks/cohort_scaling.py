@@ -6,7 +6,8 @@ chunk of gradients no matter the cohort.  This bench measures exactly
 that, with XLA's compiled-memory accounting (``benchmarks.common.
 peak_memory_bytes``): the jitted round program's temp bytes at
 cohort = 64 / 256 / 1024 with ``cohort_chunk`` fixed, next to the vmap
-executor whose stacked-gradient footprint grows linearly.
+executor (the same core at chunk = cohort), whose one chunk holds the whole
+cohort's gradients and so grows linearly.
 
 Gates (exit non-zero on failure — CI runs ``--fast``):
   * flat memory: cohort=1024 temp bytes <= 1.3x the cohort=64 run at the
@@ -14,16 +15,11 @@ Gates (exit non-zero on failure — CI runs ``--fast``):
     finite loss);
   * bit identity: chunk in {1, 8, 24 (ragged), cohort} agree bitwise —
     the streaming core accumulates in global client order, so the chunk
-    size can never change a round — and chunk=1 reproduces the
-    pre-refactor scan streaming round bit-for-bit;
-  * vmap agreement: chunk = cohort matches the pre-refactor vmap round
-    <= 1e-6.  Not gated bitwise: the vmap executor's aggregate kernel
-    reduces the cohort axis in XLA's reduce-tree order (pinned by the
-    PR-4 frozen-reference matrix), while the streaming core adds clients
-    in order.  Identical in exact arithmetic, they differ by float
-    reassociation (~1 ulp of the running sum, observed ~6e-8); pinning
-    both bitwise would pin XLA's reduction tree, which isn't stable
-    across shapes or backends.  The bench reports the observed distance.
+    size can never change a round — and chunk=1 reproduces the scan
+    executor's round bit-for-bit;
+  * vmap agreement: chunk = cohort matches the vmap executor's round
+    <= 1e-6 (both run the one streaming core at the same chunk, so the
+    bench reports a distance of 0).
   * hypergradients: two-tier sharded through_aggregation ctrl state
     matches the vmap path <= 1e-5 after a round.
 

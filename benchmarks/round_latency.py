@@ -9,10 +9,10 @@ round (exactly the old ``launch/train.py`` loop); the fused arm compiles
 ``rounds_per_call`` rounds into one donated ``lax.scan`` program and syncs
 once per chunk.
 
-A scan-strategy section times the client-sequential cohort the same two
-ways: the legacy pytree-carry scan with per-round dispatch vs the
-streaming flat-buffer accumulation (the scan carry IS the fused engine's
-dtype-group buffers; kernels/fused_update ``accumulate_pass``) under the
+A scan-strategy section times the client-sequential cohort (the cohort
+core at chunk 1, whose scan carry IS the dtype-group buffers;
+kernels/fused_update ``accumulate_pass``) the same two ways: the legacy
+tree-map engine with per-round dispatch vs the fused engine under the
 scanned driver.
 
 A backward section times the *differentiated* server step — the
@@ -40,12 +40,12 @@ import numpy as np
 from common import bench_tracker, write_bench_report
 from repro.configs.base import FedConfig
 from repro.core import (init_server_state, make_federated_round,
-                        RoundFnCache, server_opt, stack_round_inputs,
-                        weighted_mean)
+                        RoundFnCache, server_opt, stack_round_inputs)
 from repro.core import flat as flat_mod
 from repro.kernels.fused_update.ops import (TRAVERSALS_FUSED,
                                             TRAVERSALS_LEGACY,
-                                            fused_server_update)
+                                            flat_accumulate,
+                                            flat_apply_groups)
 from repro.models.model import Model
 
 # CPU smoke config: small enough to run everywhere, large enough that the
@@ -208,16 +208,28 @@ def _hypergrad_fns(model):
                               jnp.float32), params)
     t0 = jnp.asarray(5, jnp.int32)
 
+    g_stacks = flat_mod.flatten_stacked(spec, grads)
+    accum = flat_accumulate()
+
+    def normalized(w_logits):
+        w = wts * jnp.exp(w_logits)
+        return w / jnp.maximum(jnp.sum(w), 1e-30)
+
     def fused_loss(w_logits, log_lr):
         st = {"m": tuple(flat_mod.flatten_tree(spec, m_tree)),
               "v": tuple(flat_mod.flatten_tree(spec, v_tree)), "t": t0}
-        new_p, _, _ = fused_server_update(
-            params, grads, wts * jnp.exp(w_logits), st, opt=SERVER_OPT,
-            lr=jnp.exp(log_lr), clip_norm=CLIP)
+        wn = normalized(w_logits)
+        Gs = flat_mod.zeros_flat(spec)
+        for k in range(COHORT):
+            Gs = [accum(G, g[k], wn[k]) for G, g in zip(Gs, g_stacks)]
+        new_p, _, _ = flat_apply_groups(
+            spec, Gs, jnp.sqrt(flat_mod.flat_sq_norm(Gs)), params, st,
+            opt=SERVER_OPT, lr=jnp.exp(log_lr), clip_norm=CLIP)
         return model.loss(new_p, meta)[0]
 
     def legacy_loss(w_logits, log_lr):
-        G = weighted_mean(grads, wts * jnp.exp(w_logits))
+        wn = normalized(w_logits)
+        G = jax.tree.map(lambda g: jnp.tensordot(wn, g, 1), grads)
         gn = jnp.sqrt(sum(jnp.sum(jnp.square(x))
                           for x in jax.tree.leaves(G)))
         s = jnp.minimum(1.0, CLIP / jnp.maximum(gn, 1e-9))
@@ -278,8 +290,8 @@ def main():
     hg_fused, hg_legacy, hg_rel = run_hypergrad(
         model, iters=rounds * 2)
 
-    # scan strategy (client-sequential): streaming flat-buffer accumulation
-    # + scanned driver vs the legacy pytree-carry scan + per-round dispatch
+    # scan strategy (client-sequential): fused engine + scanned driver vs
+    # the legacy tree-map engine + per-round dispatch
     scan_rounds = max(rounds // 2, ROUNDS_PER_CALL)
     scan_rounds -= scan_rounds % ROUNDS_PER_CALL
     rps_scan_legacy = run_legacy(model, scan_rounds, strategy="scan")
@@ -315,10 +327,11 @@ def main():
             "relative": round(hg_fused / hg_legacy, 3),
             "hypergrad_max_rel_err": hg_rel,
         },
-        # client-sequential strategy: the scan carry is the flat dtype-group
-        # buffers (K streaming Pallas FMAs + clip/opt/write) vs the legacy
-        # pytree carry; the aggregates are bit-identical (tested), so the
-        # numerics gate mirrors the vmap one (smooth opts + adam metrics)
+        # client-sequential strategy: both arms stream the same aggregate
+        # (K Pallas FMAs into the flat dtype-group buffers); the fused arm
+        # then runs clip/opt/write in one pass, the legacy arm as tree-maps,
+        # so the numerics gate mirrors the vmap one (smooth opts + adam
+        # metrics)
         "scan_strategy": {
             "rounds": scan_rounds,
             "legacy": {"rounds_per_s": round(rps_scan_legacy, 2)},

@@ -3,20 +3,14 @@ accumulate over the flat dtype-group buffers, plus the per-client
 error-feedback state and the measured-bytes accounting.
 
 The server-side aggregate of decoded gradients is a streaming accumulation
-(one client at a time), so every cohort executor shares
-:func:`client_coded_accumulate`:
-
-  * the chunked streaming core (which the chunked/vmap/scan registrations
-    and each shard of the two-tier sharded topology all run —
-    :func:`repro.core.aggregate.chunked_cohort_gradient_coded`) computes a
-    chunk of client gradients in parallel, then runs
-    :func:`coded_aggregate_stacked` — a ``lax.scan`` over the chunk's
-    stacked cohort axis — for the codec stage (encode/decode is a few
-    flat sweeps per client, negligible next to the local updates, and the
-    scan keeps the Pallas codec kernels un-batched);
-  * the legacy scan path calls it directly inside its cohort scan (the
-    client gradient is already computed one at a time there — see
-    :func:`repro.core.aggregate.scan_cohort_gradient_coded`).
+(one client at a time): the synchronous cohort core
+(:func:`repro.core.aggregate.chunked_cohort_gradient_coded`, which every
+synchronous executor and each shard of the two-tier sharded topology runs)
+computes a chunk of client gradients in parallel, then calls
+:func:`client_coded_accumulate` for each client of the chunk inside a
+``lax.scan`` (encode/decode is a few flat sweeps per client, and the scan
+keeps the Pallas codec kernels un-batched).  The buffered-async pool
+decodes each delta without aggregating (:func:`coded_decode_stacked`).
 
 Error-feedback state layout (``state["comm"]``): ``{"residual": tuple}``
 with one ``(cohort, rows, LANES)`` fp32 buffer per dtype group — client k's
@@ -91,35 +85,6 @@ def client_coded_accumulate(codec: GradientCodec, spec: FlatSpec,
             new_accs.append(codec.decode_fma(group, acc, payload, w))
             new_res.append(transmitted * r_new + (1.0 - transmitted) * res)
     return tuple(new_accs), tuple(new_res)
-
-
-def coded_aggregate_stacked(codec: GradientCodec, spec: FlatSpec,
-                            g_groups, client_weights: jax.Array,
-                            residuals: Optional[tuple]
-                            ) -> Tuple[List[jax.Array], Optional[tuple]]:
-    """The vmap executor's codec stage: per-client encode/decode over
-    ALREADY-stacked ``(cohort, rows, LANES)`` gradient buffers, accumulated
-    into the Eq. (14) weighted mean one client at a time.
-
-    Returns (G_groups, new_residuals) — G_groups in the same layout
-    ``repro.kernels.fused_update.ops.flat_weighted_aggregate`` produces
-    (list of (rows, LANES) fp32), new_residuals stacked back to
-    (cohort, rows, LANES) per group (or None without error feedback).
-    Traced under the ``repro.codec`` named scope."""
-    from repro.core import flat as flat_mod           # lazy: import cycle
-    w = client_weights.astype(jnp.float32)
-    w = w / jnp.maximum(jnp.sum(w), 1e-30)
-
-    def body(accs, xs):
-        g_k, w_k, res_k = xs
-        accs, r_new = client_coded_accumulate(codec, spec, accs, g_k, w_k,
-                                              res_k)
-        return accs, r_new
-
-    with jax.named_scope("repro.codec"):
-        acc0 = tuple(flat_mod.zeros_flat(spec))
-        G, new_res = lax.scan(body, acc0, (tuple(g_groups), w, residuals))
-    return list(G), new_res
 
 
 def coded_decode_stacked(codec: GradientCodec, spec: FlatSpec,

@@ -227,32 +227,39 @@ class FedConfig:
     prox_mu: float = 2e-4               # FedProx coefficient
     server_opt: str = "sgd"             # sgd | sgdm | adam | yogi
     server_momentum: float = 0.0
-    cohort_strategy: str = "vmap"       # vmap (client-parallel) | scan
-                                        # (client-sequential) | chunked
-                                        # (cohort_chunk-client slices)
+    cohort_strategy: str = "vmap"       # the chunk size of the streaming
+                                        # cohort core when cohort_chunk is
+                                        # None: vmap = the whole cohort in
+                                        # one slice (client-parallel), scan
+                                        # = one client per slice
+                                        # (client-sequential); chunked
+                                        # needs cohort_chunk
     cohort_chunk: Optional[int] = None  # >=1: stream the cohort through the
-                                        # chunked executor in slices of this
-                                        # many clients — vmap within a
-                                        # slice, Pallas FMA accumulation
-                                        # across slices, so peak gradient
-                                        # memory is one chunk instead of the
-                                        # whole cohort.  Results are
-                                        # bit-identical for every chunk size
-                                        # (a ragged final chunk is padded
-                                        # with zero-weight clients).  None
-                                        # keeps the configured
-                                        # cohort_strategy; incompatible with
+                                        # core in slices of this many
+                                        # clients — vmap within a slice,
+                                        # Pallas FMA accumulation in client
+                                        # order, so peak gradient memory is
+                                        # one chunk instead of the whole
+                                        # cohort.  Results are bit-identical
+                                        # for every chunk size (a ragged
+                                        # final chunk is padded with
+                                        # zero-weight clients).  None keeps
+                                        # the configured cohort_strategy;
+                                        # incompatible with
                                         # cohort_strategy='scan' (scan IS
                                         # the chunk=1 pin of the same core).
     remat_local_steps: bool = True      # jax.checkpoint each keep-trace step
     lr_decay: float = 1.0               # multiplicative per-round client-lr decay
-    grad_agg_dtype: str = "float32"     # dtype of the aggregated gradient
+    grad_agg_dtype: str = "float32"     # dtype of the aggregate's tree
+                                        # view (the legacy_tree engine's
+                                        # handle); flat handles are fp32
     clip_norm: float = 0.0              # >0: clip the aggregated gradient G
                                         # (tames UGA's HVP amplification — the
                                         # instability the paper notes in §4.5.1)
     fused_update: bool = False          # fused flat-buffer Pallas server step
-                                        # (aggregate->clip->apply in 2 HBM
-                                        # passes; kernels/fused_update).  False
+                                        # (clip->apply in one HBM pass over
+                                        # the streamed aggregate;
+                                        # kernels/fused_update).  False
                                         # keeps the legacy tree-map path.
                                         # Implies fp32 aggregation (the fused
                                         # kernels ignore grad_agg_dtype).
@@ -266,8 +273,10 @@ class FedConfig:
                                         # the server step size, held in the
                                         # server state's 'ctrl' slot and
                                         # updated each round with ctrl_lr.
-                                        # Requires fused_update; vmap AND
-                                        # scan cohorts supported.
+                                        # Requires fused_update; every
+                                        # synchronous executor supports it
+                                        # (core/meta.py
+                                        # meta_update_through_cohort).
     ctrl_lr: float = 0.01               # hypergradient step size for the
                                         # controllable-weights state
                                         # (meta_mode='through_aggregation')

@@ -1,34 +1,25 @@
-"""Unbiased weighted aggregation over the cohort (Eq. 14) and the two cohort
-execution strategies:
+"""The synchronous cohort core: unbiased weighted aggregation over the
+cohort (Eq. 14), streamed through the flat-buffer accumulators.
 
-  * ``vmap`` (client-parallel): every client's local trajectory runs
-    simultaneously — maximal throughput, per-client parameter copies live
-    at once (right for <~1B learners);
-  * ``scan`` (client-sequential): clients run one at a time and the weighted
-    gradient accumulates in the carry — one trajectory alive at a time over
-    FSDP-sharded parameters (right for 90B/398B learners).  The fused
-    engine's form is :func:`scan_cohort_gradient_flat`, whose carry is the
-    flat-buffer layout of ``repro.core.flat`` and whose accumulate is the
-    Pallas streaming FMA (``kernels/fused_update``) — no pytree-carry
-    tree-maps, and its custom VJP yields per-client weight hypergradients
-    (``meta_mode="through_aggregation"`` under scan cohorts).
+:func:`chunked_cohort_gradient_flat` is the one core every synchronous
+executor runs (``repro.core.executors``; ``FedConfig.cohort_chunk``): the
+cohort is split into chunk-sized slices, client training is vmapped
+*within* a chunk, and each chunk's flat gradients stream into the
+per-dtype-group ``(rows, LANES)`` fp32 accumulators (``repro.core.flat``)
+with the Pallas FMA (``kernels/fused_update`` ``accumulate_pass``).  Peak
+gradient memory is one chunk, and the accumulation order is global client
+order, so every fp32 bit is invariant to the chunk size: chunk = cohort
+(the ``vmap`` registration) and chunk = 1 (``scan``, one client trajectory
+alive at a time) give the same round.  A ragged final chunk is padded with
+zero-weight clients (``acc + 0*g == acc`` bitwise).  The normalized
+weights are computed OUTSIDE the scans (one vectorized divide) so the
+through-aggregation backward never accumulates a shared-constant cotangent
+inside the nested scans — that is what keeps the ctrl hypergradients
+chunk-invariant too, not just the forward pass.
 
-Both produce bit-identical math (property-tested).  Under pjit, the cohort
-axis of ``cohort_batch`` is sharded over the mesh (data, pod) axes so the
-weighted mean lowers to an all-reduce over ICI/DCN — the FL parameter-server
-gather, TPU-style.
-
-:func:`chunked_cohort_gradient_flat` generalizes both into ONE streaming
-core (``FedConfig.cohort_chunk``): the cohort is split into chunk-sized
-slices, client training is vmapped *within* a chunk, and each chunk's flat
-gradients stream into the per-dtype-group accumulators with the same Pallas
-FMA — peak gradient memory is one chunk, the accumulation order is global
-client order, so every fp32 bit is invariant to the chunk size.  A ragged
-final chunk is padded with zero-weight clients (``acc + 0*g == acc``
-bitwise).  The normalized weights are computed OUTSIDE the scans (one
-vectorized divide) so the through-aggregation backward never accumulates a
-shared-constant cotangent inside the nested scans — that is what keeps the
-ctrl hypergradients chunk-invariant too, not just the forward pass.
+:func:`chunked_cohort_gradient_coded` puts the lossy uplink codec between
+each client and the accumulator; :func:`scan_cohort_deltas_flat` keeps the
+per-client deltas for the buffered-async pool instead of accumulating them.
 """
 from __future__ import annotations
 
@@ -39,147 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 
 PyTree = Any
-
-
-def weighted_mean(trees: PyTree, weights: jax.Array, dtype=jnp.float32):
-    """trees: pytree with leading cohort axis; weights: (cohort,) n_k."""
-    w = weights.astype(jnp.float32)
-    w = w / jnp.maximum(jnp.sum(w), 1e-30)
-
-    def agg(x):
-        wx = w.reshape((-1,) + (1,) * (x.ndim - 1)).astype(jnp.float32)
-        return jnp.sum(x.astype(jnp.float32) * wx, axis=0).astype(dtype)
-
-    return jax.tree.map(agg, trees)
-
-
-def cohort_gradient(client_update: Callable, w_t: PyTree, cohort_batch: PyTree,
-                    client_weights: jax.Array, lr, rng, *,
-                    strategy: str = "vmap", agg_dtype=jnp.float32,
-                    spmd_axis_name=None, grad_shardings=None,
-                    aggregate: bool = True) -> Tuple[PyTree, jax.Array]:
-    """Run ``client_update`` for every client and aggregate Eq.(14).
-
-    cohort_batch: leaves (cohort, b, ...); client_weights: (cohort,) = n_k.
-    ``spmd_axis_name`` (e.g. ("pod","data")) pins every per-client
-    intermediate — local parameter trajectories, per-client gradients — to
-    the mesh cohort axes instead of letting GSPMD replicate them (the 37x
-    HBM blow-up of §Perf iteration 1).  Returns (G, mean_client_loss).
-
-    ``aggregate=False`` (vmap strategy only) skips the weighted mean and
-    returns the *stacked* per-client gradients (cohort, *param) so the
-    fused server engine can do the Eq.(14) reduce inside its Pallas pass
-    together with the clip-norm sum-of-squares."""
-    cohort = client_weights.shape[0]
-    rngs = (jax.random.split(rng, cohort) if rng is not None
-            else jnp.zeros((cohort, 2), jnp.uint32))
-
-    if strategy == "vmap":
-        def one(batch, r):
-            return client_update(w_t, batch,
-                                 lr, r if rng is not None else None)
-        g_all, losses = jax.vmap(one, spmd_axis_name=spmd_axis_name)(
-            cohort_batch, rngs)
-        if grad_shardings is not None:
-            g_all = jax.lax.with_sharding_constraint(g_all, grad_shardings)
-        wsum = jnp.maximum(jnp.sum(client_weights.astype(jnp.float32)), 1e-30)
-        mean_loss = jnp.sum(losses * client_weights.astype(jnp.float32)) / wsum
-        if not aggregate:
-            return g_all, mean_loss
-        G = weighted_mean(g_all, client_weights, agg_dtype)
-        return G, mean_loss
-
-    if strategy == "scan":
-        if not aggregate:
-            raise NotImplementedError(
-                "stacked gradients defeat the point of the scan strategy "
-                "(one client trajectory alive at a time); the fused engine "
-                "streams the accumulation instead — use "
-                "scan_cohort_gradient_flat")
-        wsum = jnp.maximum(jnp.sum(client_weights.astype(jnp.float32)), 1e-30)
-
-        def body(carry, inp):
-            G_acc, l_acc = carry
-            batch, weight, r = inp
-            g_k, l_k = client_update(
-                w_t, batch, lr, r if rng is not None else None)
-            wk = weight.astype(jnp.float32) / wsum
-            G_acc = jax.tree.map(
-                lambda a, g: a + wk * g.astype(jnp.float32), G_acc, g_k)
-            return (G_acc, l_acc + wk * l_k), None
-
-        G0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), w_t)
-        body = jax.checkpoint(body, prevent_cse=False)
-        (G, mean_loss), _ = lax.scan(
-            body, (G0, jnp.zeros((), jnp.float32)),
-            (cohort_batch, client_weights, rngs))
-        G = jax.tree.map(lambda g: g.astype(agg_dtype), G)
-        return G, mean_loss
-
-    raise ValueError(strategy)
-
-
-def scan_cohort_gradient_flat(client_update: Callable, w_t: PyTree,
-                              cohort_batch: PyTree,
-                              client_weights: jax.Array, lr, rng, *,
-                              spec, loss_weights: Optional[jax.Array] = None,
-                              use_ref: bool = False,
-                              interpret: Optional[bool] = None
-                              ) -> Tuple[list, jax.Array]:
-    """Client-sequential cohort execution fused into the flat-buffer engine.
-
-    The scan carry IS the fused engine's per-dtype-group ``(rows, LANES)``
-    fp32 buffers: each step runs one client's local update, flattens its
-    gradient (:func:`repro.core.flat.flatten_tree` — one client in flat
-    form at a time), and FMAs it into the accumulators with the Pallas
-    ``accumulate_pass`` kernel — one HBM sweep per client, no pytree-carry
-    tree-maps, no flatten round-trip of the aggregate.  Same per-client rng
-    split and fp32 accumulation order as ``cohort_gradient(strategy=
-    "scan")``, so results are bit-compatible with the legacy carry.
-
-    Differentiable w.r.t. ``client_weights``: the accumulate custom VJP
-    emits dw_k = <g_k, dG> with g_k recomputed under ``jax.checkpoint``
-    (one client trajectory's residuals alive at a time) — exactly the
-    ``meta_mode="through_aggregation"`` hypergradient.
-
-    Returns (G_groups, mean_loss): the Eq. (14) weighted-mean flat buffers
-    (list, one per dtype group of ``spec``) plus the weighted mean client
-    loss.  Feed G_groups to ``fused_apply_flat`` for clip+optimizer+write.
-    ``loss_weights`` (default: ``client_weights``) weights the loss metric
-    separately from the aggregation — through_aggregation aggregates with
-    the controllable eff_w but reports the n_k-weighted loss so the metric
-    means the same thing on every strategy.
-    """
-    from repro.core import flat as flat_mod           # lazy: import cycle
-    from repro.kernels.fused_update.ops import flat_accumulate
-
-    cohort = client_weights.shape[0]
-    rngs = (jax.random.split(rng, cohort) if rng is not None
-            else jnp.zeros((cohort, 2), jnp.uint32))
-    w32 = client_weights.astype(jnp.float32)
-    wsum = jnp.maximum(jnp.sum(w32), 1e-30)
-    lw32 = (w32 if loss_weights is None
-            else loss_weights.astype(jnp.float32))
-    lwsum = (wsum if loss_weights is None
-             else jnp.maximum(jnp.sum(lw32), 1e-30))
-    accum = flat_accumulate(use_ref, interpret)
-
-    def body(carry, inp):
-        accs, l_acc = carry
-        batch, weight, lweight, r = inp
-        g_k, l_k = client_update(
-            w_t, batch, lr, r if rng is not None else None)
-        wk = weight / wsum
-        g_bufs = flat_mod.flatten_tree(spec, g_k)
-        accs = tuple(accum(a, g, wk) for a, g in zip(accs, g_bufs))
-        return (accs, l_acc + (lweight / lwsum) * l_k), None
-
-    body = jax.checkpoint(body, prevent_cse=False)
-    acc0 = tuple(flat_mod.zeros_flat(spec))
-    (G, mean_loss), _ = lax.scan(
-        body, (acc0, jnp.zeros((), jnp.float32)),
-        (cohort_batch, w32, lw32, rngs))
-    return list(G), mean_loss
 
 
 def _chunk_cohort_inputs(cohort_batch: PyTree, wn: jax.Array, lwn: jax.Array,
@@ -215,7 +65,7 @@ def _stream_flat_chunks(client_update: Callable, w_t: PyTree, lr,
                         spmd_axis_name=None, use_ref: bool = False,
                         interpret: Optional[bool] = None, codec=None,
                         residuals_c: Optional[tuple] = None):
-    """The chunked streaming core shared by the chunked/vmap/scan executors
+    """The chunked streaming core shared by every synchronous executor
     and each shard of the two-tier sharded executor.
 
     Outer ``lax.scan`` over chunks; within a chunk the clients vmap, then an
@@ -257,11 +107,10 @@ def _stream_flat_chunks(client_update: Callable, w_t: PyTree, lr,
             return client_update(w_t, batch, lr, r if has_rng else None)
 
         if wn_c.shape[1] == 1 and spmd_axis_name is None:
-            # chunk width 1 (the scan registration): run the client
-            # UNBATCHED.  A width-1 vmap changes how XLA:CPU emits the
-            # client loss reduction (observed 1-ulp per-client loss drift),
-            # and this path is pinned bit-identical to the unbatched
-            # legacy-scan and async-delta bodies.
+            # chunk width 1: run the client UNBATCHED.  A width-1 vmap
+            # changes how XLA:CPU emits the client loss reduction (observed
+            # 1-ulp per-client loss drift), and this path is pinned
+            # bit-identical to the unbatched async-delta body.
             g_one, l_one = one(jax.tree.map(lambda x: x[0], cb), rc[0])
             g_stack = jax.tree.map(lambda x: x[None], g_one)
             losses = l_one[None]
@@ -304,16 +153,21 @@ def chunked_cohort_gradient_flat(client_update: Callable, w_t: PyTree,
                                  spmd_axis_name=None, use_ref: bool = False,
                                  interpret: Optional[bool] = None
                                  ) -> Tuple[list, jax.Array]:
-    """Chunked streaming cohort execution — the general core behind the
-    ``chunked`` executor, of which ``scan`` is the chunk=1 pin.
+    """Chunked streaming cohort execution — the core behind every
+    synchronous executor (``vmap`` pins chunk = cohort, ``scan`` chunk = 1).
 
-    Same per-client rng split (over the TRUE cohort, so rng streams are
-    chunking-invariant), the same normalized FMA weights and the same
-    sequential loss accumulation as :func:`scan_cohort_gradient_flat`, so
-    ``chunk=1`` reproduces the scan path bit-for-bit while larger chunks
-    trade peak gradient memory (one chunk of trajectories) for vmap
-    throughput.  Differentiable w.r.t. ``client_weights`` exactly like the
-    scan form.  Returns (G_groups, mean_loss)."""
+    The per-client rng split runs over the TRUE cohort, so rng streams are
+    chunking-invariant; with the pre-normalized FMA weights and the
+    client-order loss accumulation, every chunk size gives the same bits,
+    and larger chunks trade peak gradient memory (one chunk of
+    trajectories) for vmap throughput.  Differentiable w.r.t.
+    ``client_weights``: the accumulate custom VJP emits dw_k = <g_k, dG>
+    with g_k recomputed per chunk under ``jax.checkpoint`` — the
+    ``meta_mode="through_aggregation"`` hypergradient.  ``loss_weights``
+    (default: ``client_weights``) weight the loss metric separately, so
+    through_aggregation aggregates with the controllable eff_w but reports
+    the n_k-weighted loss.  Returns (G_groups, mean_loss): the Eq. (14)
+    weighted-mean flat buffers, one per dtype group of ``spec``."""
     cohort = client_weights.shape[0]
     chunk = max(1, min(int(chunk), cohort))
     rngs = (jax.random.split(rng, cohort) if rng is not None
@@ -346,9 +200,9 @@ def chunked_cohort_gradient_coded(client_update: Callable, w_t: PyTree,
                                              Optional[tuple]]:
     """:func:`chunked_cohort_gradient_flat` with the lossy uplink codec
     between each client and the accumulator (chunk-local decode-FMA via
-    ``kernels/comm``) — the chunked generalization of
-    :func:`scan_cohort_gradient_coded` (identical at ``chunk=1``; loss is
-    weighted by the aggregation weights like the scan-coded path).
+    ``kernels/comm``); the loss is weighted by the aggregation weights.
+    Not differentiable w.r.t. the weights — lossy codecs are
+    ``meta_mode='post'``-only (guarded by the round builder).
 
     ``residuals``: per-group ``(cohort, rows, LANES)`` error-feedback
     stacks or None.  Pad slots of a ragged chunk carry weight 0, so the
@@ -397,10 +251,10 @@ def scan_cohort_deltas_flat(client_update: Callable, w_t: PyTree,
     scan strategy's one-delta-alive memory profile; the async runtime pays
     it because the pool holds per-delta state anyway.)
 
-    Per-client rng split and the sequential loss accumulation order are
-    IDENTICAL to :func:`scan_cohort_gradient_flat`, so feeding these deltas
-    through the same streaming-FMA sequence reproduces the synchronous
-    scan aggregation bit-for-bit — the fault-free async == sync gate."""
+    The per-client rng split is the one :func:`chunked_cohort_gradient_flat`
+    uses, so feeding these deltas through the same streaming-FMA sequence
+    reproduces the synchronous aggregation bit-for-bit — the fault-free
+    async == sync gate."""
     from repro.core import flat as flat_mod           # lazy: import cycle
 
     cohort = client_weights.shape[0]
@@ -421,50 +275,3 @@ def scan_cohort_deltas_flat(client_update: Callable, w_t: PyTree,
     mean_loss, stacked = lax.scan(
         body, jnp.zeros((), jnp.float32), (cohort_batch, lw32, rngs))
     return list(stacked), mean_loss
-
-
-def scan_cohort_gradient_coded(client_update: Callable, w_t: PyTree,
-                               cohort_batch: PyTree,
-                               client_weights: jax.Array, lr, rng, *,
-                               spec, codec, residuals: Optional[tuple] = None
-                               ) -> Tuple[list, jax.Array, Optional[tuple]]:
-    """:func:`scan_cohort_gradient_flat` with a lossy uplink codec
-    (:mod:`repro.comm`) between each client and the accumulator: client k's
-    flattened gradient is encoded, (optionally) error-compensated against
-    its ``residuals`` slot, decoded server-side and folded into the flat
-    Eq. (14) accumulators — for ``int8``/``sign1bit`` the decode fuses into
-    the streaming FMA itself (``kernels/comm`` dequantize-FMA), so a coded
-    client costs one encode sweep plus the same single FMA sweep per group
-    as the uncompressed path (error feedback rides the encode sweep).
-
-    residuals: per-group ``(cohort, rows, LANES)`` error-feedback stacks
-    (``state["comm"]["residual"]``) or None.  Returns (G_groups, mean_loss,
-    new_residuals) with new_residuals stacked in cohort order (None when
-    ``residuals`` is None).  Not differentiable w.r.t. the weights — lossy
-    codecs are ``meta_mode='post'``-only (guarded by the round builder)."""
-    from repro.comm.transport import client_coded_accumulate  # lazy: cycle
-    from repro.core import flat as flat_mod           # lazy: import cycle
-
-    cohort = client_weights.shape[0]
-    rngs = (jax.random.split(rng, cohort) if rng is not None
-            else jnp.zeros((cohort, 2), jnp.uint32))
-    w32 = client_weights.astype(jnp.float32)
-    wsum = jnp.maximum(jnp.sum(w32), 1e-30)
-
-    def body(carry, inp):
-        accs, l_acc = carry
-        batch, weight, r, res_k = inp
-        g_k, l_k = client_update(
-            w_t, batch, lr, r if rng is not None else None)
-        wk = weight / wsum
-        g_bufs = flat_mod.flatten_tree(spec, g_k)
-        accs, r_new = client_coded_accumulate(codec, spec, accs, g_bufs,
-                                              wk, res_k)
-        return (accs, l_acc + wk * l_k), r_new
-
-    body = jax.checkpoint(body, prevent_cse=False)
-    acc0 = tuple(flat_mod.zeros_flat(spec))
-    (G, mean_loss), new_res = lax.scan(
-        body, (acc0, jnp.zeros((), jnp.float32)),
-        (cohort_batch, w32, rngs, residuals))
-    return list(G), mean_loss, new_res

@@ -252,15 +252,15 @@ def make_async_tick(model: Model, fed, *, algorithm: Optional[str] = None,
             wn = w_eff / wsum
 
             # streaming FMA over the pool slots — the same accumulate_pass
-            # sequence as scan_cohort_gradient_flat, so a fault-free
-            # K=cap=cohort flush reproduces the sync scan bits exactly
+            # sequence as the synchronous cohort core, so a fault-free
+            # K=cap=cohort flush reproduces the sync round's bits exactly
             def acc_body(accs, xs):
                 gs, wi = xs
                 return tuple(accum(acc, g, wi)
                              for acc, g in zip(accs, gs)), None
 
             accs, _ = lax.scan(acc_body, tuple(zeros_flat(spec)), (pool, wn))
-            handle = FlatAggregate(list(accs), spec, sq_norm=None)
+            handle = FlatAggregate(list(accs), spec)
             with jax.named_scope("repro.server"):
                 new_p, new_o, gn = eng.apply(params_f, handle, opt_f,
                                              lr=server_lr)

@@ -6,8 +6,8 @@ update (clip -> optimizer -> parameter write).  Engines declare:
 
   * ``accepts`` / ``preferred`` — which handle kinds they consume, so the
     round builder can ask the executor for the right one (``FusedFlatEngine``
-    prefers flat buffers but still accepts a sharded tree by wrapping it as
-    a one-client stack, exactly the pre-redesign fallback);
+    prefers flat buffers but still accepts a tree handle, which it
+    flattens);
   * ``meta_capabilities`` — which FedMeta modes the engine can power.
     ``"through_aggregation"`` means the engine's apply is differentiable
     w.r.t. the aggregate and the step size (the fused engine's hand-written
@@ -36,10 +36,9 @@ import jax.numpy as jnp
 
 from repro.core import server_opt
 from repro.core.executors import FlatAggregate, TreeAggregate
-from repro.core.flat import flat_sq_norm, make_flat_spec
+from repro.core.flat import flat_sq_norm, flatten_tree, make_flat_spec
 from repro.core.registry import Registry
 from repro.kernels.fused_update.ops import (flat_apply_groups,
-                                            fused_server_update,
                                             init_flat_opt_state)
 
 PyTree = Any
@@ -167,16 +166,11 @@ class FusedFlatEngine(ServerEngine):
     def apply(self, params, handle, opt_state, *, lr):
         if isinstance(handle, TreeAggregate):
             # pre-aggregated tree handles (custom executors; the built-in
-            # four all produce flat): run the engine over a one-client
-            # stack so the flat layout needn't re-express the tree
-            g_stack = jax.tree.map(lambda x: x[None], handle.tree)
-            return fused_server_update(
-                params, g_stack, jnp.ones((1,), jnp.float32), opt_state,
-                opt=self._opt, lr=lr, clip_norm=self._clip,
-                momentum=self._momentum)
+            # ones all produce flat): flatten into the engine's layout
+            spec = make_flat_spec(params)
+            handle = FlatAggregate(flatten_tree(spec, handle.tree), spec)
         assert isinstance(handle, FlatAggregate), type(handle)
-        gn = (jnp.sqrt(handle.sq_norm) if handle.sq_norm is not None
-              else jnp.sqrt(flat_sq_norm(handle.groups)))
+        gn = jnp.sqrt(flat_sq_norm(handle.groups))
         return flat_apply_groups(
             handle.spec, handle.groups, gn, params, opt_state,
             opt=self._opt, lr=lr, clip_norm=self._clip,

@@ -5,8 +5,7 @@ local updates and always yields a **uniform aggregate handle** so server
 engines never inspect the strategy:
 
   * :class:`FlatAggregate` — the fused engine's per-dtype-group
-    ``(rows, LANES)`` fp32 buffers holding the Eq. (14) weighted mean
-    (``sq_norm`` carries ``||G||^2`` when pass 1 already reduced it);
+    ``(rows, LANES)`` fp32 buffers holding the Eq. (14) weighted mean;
   * :class:`TreeAggregate` — the weighted-mean pytree (the form the legacy
     tree-map engine consumes).
 
@@ -18,8 +17,7 @@ flat gradients stream into the dtype-group accumulators via the Pallas FMA
 kernels, so peak gradient memory is one chunk no matter the cohort.
 
   * ``chunked`` — the core itself (``chunk = cohort_chunk``);
-  * ``vmap``    — ``chunk = cohort`` (whole cohort in one slice; keeps the
-    retained-stack aggregate kernel for its handles);
+  * ``vmap``    — ``chunk = cohort`` (whole cohort in one slice);
   * ``scan``    — ``chunk = 1`` (one client trajectory alive at a time);
   * ``sharded`` — the two-tier topology: the cohort axis splits across the
     mesh batch axes under ``shard_map``, each shard streams its slice
@@ -32,8 +30,7 @@ Because all four share the streaming core, they ALL declare
 ``supports_reweight = True`` (per-client ``dw_k`` hypergradients via the
 accumulate custom VJP, client trajectories recomputed per chunk under
 ``jax.checkpoint``) and lossy ``codec_capabilities`` (chunk-local
-decode-FMA via ``kernels/comm``) — including ``sharded``, which used to
-pre-aggregate per leaf and declare both unsupported.
+decode-FMA via ``kernels/comm``), and every chunk size gives the same bits.
 
 Register a new strategy with :func:`register_executor`; the factory
 receives the :class:`~repro.configs.base.FedConfig` plus the round
@@ -50,12 +47,10 @@ import jax.numpy as jnp
 from repro.core.aggregate import (_chunk_cohort_inputs, _stream_flat_chunks,
                                   chunked_cohort_gradient_coded,
                                   chunked_cohort_gradient_flat,
-                                  cohort_gradient, scan_cohort_deltas_flat,
-                                  scan_cohort_gradient_flat)
-from repro.core.flat import (FlatSpec, constrain_groups, make_flat_spec,
-                             unflatten_tree, with_pspecs)
+                                  scan_cohort_deltas_flat)
+from repro.core.flat import (FlatSpec, constrain_groups, flatten_stacked,
+                             make_flat_spec, unflatten_tree, with_pspecs)
 from repro.core.registry import Registry
-from repro.kernels.fused_update.ops import flat_weighted_aggregate
 
 PyTree = Any
 
@@ -72,7 +67,6 @@ class FlatAggregate:
     """Eq. (14) weighted mean in the fused engine's flat layout."""
     groups: list                       # per-dtype-group (rows, LANES) fp32
     spec: FlatSpec
-    sq_norm: Optional[jax.Array] = None   # ||G||^2 if pass 1 computed it
 
 
 @dataclasses.dataclass
@@ -196,7 +190,8 @@ class ChunkedExecutor(CohortExecutor):
     (Pallas streaming kernels), chunks run under an outer ``lax.scan`` with
     ``jax.checkpoint`` — peak gradient memory is ONE chunk, and the fp32
     accumulation order (hence every output bit) is invariant to the chunk
-    size.  vmap/scan/sharded subclass this with pinned chunk sizes."""
+    size.  vmap/scan pin the chunk size; sharded splits the cohort across
+    the mesh and streams each shard's slice through the same core."""
     name = "chunked"
     produces = frozenset({"flat", "tree"})
     supports_reweight = True
@@ -205,7 +200,6 @@ class ChunkedExecutor(CohortExecutor):
     def __init__(self, fed, *, spmd_axis_name=None, grad_shardings=None):
         self._agg_dtype = jnp.dtype(fed.grad_agg_dtype)
         self._spmd = spmd_axis_name
-        self._shardings = grad_shardings
         self._chunk = (None if fed.cohort_chunk is None
                        else int(fed.cohort_chunk))
 
@@ -240,7 +234,7 @@ class ChunkedExecutor(CohortExecutor):
             # same streamed fp32 buffers, viewed as a pytree in agg dtype
             return TreeAggregate(
                 unflatten_tree(spec, Gs, dtype=self._agg_dtype)), loss
-        return FlatAggregate(Gs, spec, sq_norm=None), loss
+        return FlatAggregate(Gs, spec), loss
 
     def run_coded(self, client_update, params, cohort_batch, client_weights,
                   lr, rng, *, codec, comm):
@@ -250,7 +244,7 @@ class ChunkedExecutor(CohortExecutor):
             client_update, params, cohort_batch, client_weights, lr, rng,
             spec=spec, codec=codec, residuals=res)
         new_comm = {"residual": new_res} if comm is not None else None
-        return FlatAggregate(Gs, spec, sq_norm=None), loss, new_comm
+        return FlatAggregate(Gs, spec), loss, new_comm
 
     def reweightable(self, client_update, params, cohort_batch,
                      client_weights, lr, rng):
@@ -264,20 +258,15 @@ class ChunkedExecutor(CohortExecutor):
             Gs, loss = self._flat(client_update, params, cohort_batch,
                                   weights, lr, rng, spec=spec,
                                   loss_weights=client_weights)
-            return FlatAggregate(Gs, spec, sq_norm=None), loss
+            return FlatAggregate(Gs, spec), loss
 
         return ReweightableCohort(aggregate=aggregate)
 
 
 @register_executor("vmap")
 class VmapExecutor(ChunkedExecutor):
-    """Client-parallel: the whole cohort is one chunk.  Keeps the
-    retained-stack fast path for its plain/reweightable handles — every
-    local trajectory runs simultaneously, the (cohort, *param) gradient
-    stack stays live, and the differentiable aggregate kernel (pass 1)
-    reduces it, fusing the clip-norm ``||G||^2``.  The coded path streams
-    through the chunked core (chunk = cohort: one vmap, then the
-    per-client uplink scan)."""
+    """Client-parallel: the chunked core pinned at chunk = cohort, every
+    local trajectory in one vmapped slice."""
     name = "vmap"
 
     def __init__(self, fed, *, spmd_axis_name=None, grad_shardings=None):
@@ -285,67 +274,16 @@ class VmapExecutor(ChunkedExecutor):
                          grad_shardings=grad_shardings)
         self._chunk = None               # whole cohort in one slice
 
-    def _stack(self, client_update, params, cohort_batch, client_weights,
-               lr, rng):
-        return cohort_gradient(
-            client_update, params, cohort_batch, client_weights, lr, rng,
-            strategy="vmap", agg_dtype=self._agg_dtype,
-            spmd_axis_name=self._spmd, aggregate=False)
-
-    def run(self, client_update, params, cohort_batch, client_weights,
-            lr, rng, *, kind):
-        if kind == "tree":
-            G, loss = cohort_gradient(
-                client_update, params, cohort_batch, client_weights, lr,
-                rng, strategy="vmap", agg_dtype=self._agg_dtype,
-                spmd_axis_name=self._spmd, grad_shardings=self._shardings)
-            return TreeAggregate(G), loss
-        g_stack, loss = self._stack(client_update, params, cohort_batch,
-                                    client_weights, lr, rng)
-        spec = make_flat_spec(params)
-        Gs, ssq = flat_weighted_aggregate(spec, g_stack, client_weights)
-        return FlatAggregate(Gs, spec, sq_norm=ssq), loss
-
-    def reweightable(self, client_update, params, cohort_batch,
-                     client_weights, lr, rng):
-        # clients run ONCE here (loss already n_k-weighted); aggregate()
-        # only re-reduces the retained stack under new weights (cheap,
-        # differentiable via the aggregate kernel's custom VJP)
-        spec = make_flat_spec(params)
-        g_stack, loss = self._stack(client_update, params, cohort_batch,
-                                    client_weights, lr, rng)
-
-        def aggregate(weights):
-            Gs, ssq = flat_weighted_aggregate(spec, g_stack, weights)
-            return FlatAggregate(Gs, spec, sq_norm=ssq), loss
-
-        return ReweightableCohort(aggregate=aggregate)
-
 
 @register_executor("scan")
 class ScanExecutor(ChunkedExecutor):
     """Client-sequential: the chunked core pinned at chunk = 1, one
-    trajectory alive at a time.  The streamed forward (plain and coded)
-    is inherited; the reweightable form keeps the dedicated cohort scan
-    (:func:`repro.core.aggregate.scan_cohort_gradient_flat`) whose
-    backward accumulation order the through_aggregation ctrl tests pin."""
+    trajectory alive at a time."""
     name = "scan"
 
     def __init__(self, fed, *, spmd_axis_name=None, grad_shardings=None):
         super().__init__(fed, spmd_axis_name=None, grad_shardings=None)
         self._chunk = 1                  # one client per slice
-
-    def reweightable(self, client_update, params, cohort_batch,
-                     client_weights, lr, rng):
-        spec = self._make_spec(params)
-
-        def aggregate(weights):
-            Gs, loss = scan_cohort_gradient_flat(
-                client_update, params, cohort_batch, weights, lr, rng,
-                spec=spec, loss_weights=client_weights)
-            return FlatAggregate(Gs, spec, sq_norm=None), loss
-
-        return ReweightableCohort(aggregate=aggregate)
 
 
 def _mesh_from_shardings(shardings) -> Optional[Any]:
@@ -475,8 +413,8 @@ class ShardedExecutor(ChunkedExecutor):
             in_specs=(P(), P(self._ba), P(self._ba), P(self._ba),
                       P(self._ba), P(self._ba)),
             out_specs=(P(), P(), P(self._ba)),
-            # the accumulate/aggregate custom_vjp kernels inside the shard
-            # body break shard_map's varying-axis inference
+            # the accumulate custom_vjp kernels inside the shard body break
+            # shard_map's varying-axis inference
             check_vma=False))
         G, loss, res_out = fn(params, cohort_batch, wn, lwn, rngs, res_p)
         G = constrain_groups(spec, G)
@@ -557,11 +495,18 @@ class BufferedAsyncExecutor(CohortExecutor):
         ``client_weights`` only weight the loss metric here — aggregation
         weights are the pool's business at flush time."""
         if self._base == "vmap":
-            from repro.core.flat import flatten_stacked
-            g_stack, loss = cohort_gradient(
-                client_update, params, cohort_batch, client_weights, lr,
-                rng, strategy="vmap", spmd_axis_name=self._spmd,
-                aggregate=False)
+            cohort = client_weights.shape[0]
+            rngs = (jax.random.split(rng, cohort) if rng is not None
+                    else jnp.zeros((cohort, 2), jnp.uint32))
+
+            def one(batch, r):
+                return client_update(params, batch, lr,
+                                     r if rng is not None else None)
+
+            g_stack, losses = jax.vmap(one, spmd_axis_name=self._spmd)(
+                cohort_batch, rngs)
+            w32 = client_weights.astype(jnp.float32)
+            loss = jnp.sum(losses * w32) / jnp.maximum(jnp.sum(w32), 1e-30)
             return flatten_stacked(spec, g_stack), loss
         return scan_cohort_deltas_flat(
             client_update, params, cohort_batch, client_weights, lr, rng,

@@ -10,8 +10,10 @@ COMPOSING three plugin registries instead of a hand-wired branch tree:
                                     state["comm"] — the lossy-transport
                                     simulation, post-meta-mode only)
                -> unbiased aggregation (CohortExecutor registry,
-                                    repro.core.executors: vmap / scan /
-                                    sharded -> a uniform aggregate handle)
+                                    repro.core.executors: chunked / vmap /
+                                    scan / sharded, all over the one
+                                    streaming cohort core -> a uniform
+                                    aggregate handle)
                -> server update    (ServerEngine registry,
                                     repro.core.engines: legacy_tree /
                                     fused_flat, with declared
@@ -23,16 +25,18 @@ COMPOSING three plugin registries instead of a hand-wired branch tree:
 ``make_federated_round(model, fed)`` returns ``round_fn(state, cohort_batch,
 meta_batch, client_weights, rng) -> (state, metrics)`` suitable for
 ``jax.jit`` with in/out shardings from ``repro.sharding``.  The executor
-and engine are resolved from ``fed`` (``cohort_strategy``, ``fused_update``,
-``grad_shardings``) or overridden by registry name via the ``algorithm`` /
-``executor`` / ``engine`` keywords; every supported combination is
-numerically identical to the pre-registry (PR 3) paths (equivalence-matrix
-tested).
+and engine are resolved from ``fed`` (``cohort_chunk``,
+``cohort_strategy``, ``fused_update``, ``grad_shardings``) or overridden by
+registry name via the ``algorithm`` / ``executor`` / ``engine`` keywords.
+Every synchronous executor streams the cohort through the same core
+(``repro.core.aggregate.chunked_cohort_gradient_flat``), so the chunk size
+never changes a round's bits; the tests compare each combination with a
+plain tree-map reference round.
 
 Partial participation / straggler dropout: ``fed.participation < 1`` draws
 a per-round Bernoulli mask over the cohort and zeroes dropped clients'
-aggregation weights — inside the existing weighted-mean / fused-accumulate
-math, so every executor and engine supports it unchanged (a w=0 client
+aggregation weights — inside the streaming accumulate math, so every
+executor and engine supports it unchanged (a w=0 client
 contributes nothing to Eq. 14 and the surviving weights renormalize).
 
 ``rounds_per_call=K`` wraps the round body in ``lax.scan`` so drivers

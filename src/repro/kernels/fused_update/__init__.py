@@ -1,4 +1,5 @@
-from repro.kernels.fused_update.ops import (fused_server_update,
+from repro.kernels.fused_update.ops import (flat_accumulate,
+                                            flat_apply_groups,
                                             init_flat_opt_state)
 
-__all__ = ["fused_server_update", "init_flat_opt_state"]
+__all__ = ["flat_accumulate", "flat_apply_groups", "init_flat_opt_state"]

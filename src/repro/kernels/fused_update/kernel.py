@@ -1,49 +1,36 @@
-"""Pallas TPU kernels for the fused server update (aggregate -> clip ->
-apply) over flat fp32 buffers (layout: ``repro.core.flat``).
+"""Pallas TPU kernels for the fused server update over flat fp32 buffers
+(layout: ``repro.core.flat``).
 
-Two forward kernels, at most two passes over HBM per round:
+Two forward kernels:
 
-  * :func:`aggregate_pass` — grid walks row tiles of the stacked client
-    gradients ``(cohort, rows, LANES)``; each step reduces the cohort axis
-    with the normalized weights (Eq. 14) and accumulates the global
-    sum-of-squares into a (1, 1) output revisited by every grid step (TPU
-    grids are sequential, so the accumulation is well-defined — same idiom
-    as the flash_attention kv axis).
+  * :func:`accumulate_pass` — fused-multiply-add of ONE client's flattened
+    gradient into the group accumulator, ``acc + w_k * g_k``, in a single
+    HBM sweep.  The cohort core's scan carries the flat buffer itself, so
+    a round's Eq. (14) aggregate is one streaming accumulate per client
+    plus one :func:`update_pass` — no pytree-carry tree-maps, no reduce
+    over a stacked cohort.  The output aliases ``acc`` (``input_output_aliases``),
+    so a loop-carried accumulator is updated in place: without the alias
+    XLA copies the whole carry before every call, since the call still
+    reads it.
   * :func:`update_pass` — grid walks row tiles of the aggregated gradient,
     applies the clip scale and the server optimizer (sgd/sgdm/adam/yogi)
     and writes the new parameters (+ m/v slots) in one sweep.  Traced
     scalars (clip scale, lr, bias corrections) ride in a (1, 4) SMEM
     operand; static hyper-parameters (momentum, b1, b2, eps) are baked in.
 
-A third forward kernel serves the client-sequential (scan) cohort
-strategy, where the per-client gradients are never stacked:
-
-  * :func:`accumulate_pass` — fused-multiply-add of ONE client's flattened
-    gradient into the group accumulator, ``acc + w_k * g_k``, in a single
-    HBM sweep.  The scan carry is the flat buffer itself, so a scan round
-    is K streaming accumulates plus the same :func:`update_pass` — no
-    pytree-carry tree-maps, no flatten round-trip of the aggregate.  The
-    output aliases ``acc`` (``input_output_aliases``), so a loop-carried
-    accumulator is updated in place: without the alias XLA copies the
-    whole carry before every call, since the call still reads it.
-
-Backward kernels give each pair a hand-written VJP (wired up by the
+Backward kernels give each a hand-written VJP (wired up by the
 ``jax.custom_vjp`` ops in ``ops.py``) so meta-learning *through* the
 aggregation never falls back to XLA re-differentiating the engine:
 
-  * :func:`aggregate_pass_bwd` — scatters the total cotangent of the mean
-    ``dG + 2*dssq*G`` back to the ``(cohort, rows, LANES)`` stack
-    (``dg_k = w_k * dGt``) and accumulates the per-client weight cotangents
-    ``dw_k = <g_k, dGt>`` into a (cohort, 1) output revisited by every grid
-    step.
-  * :func:`accumulate_pass_bwd` — for the streaming FMA: ``d_acc`` is the
-    identity (handled by the caller), ``dg_k = w_k * d_out`` and
-    ``dw_k = <g_k, d_out>`` accumulated into a (1, 1) output.  Because the
+  * :func:`accumulate_pass_bwd` — ``d_acc`` is the identity (handled by the
+    caller), ``dg_k = w_k * d_out`` and ``dw_k = <g_k, d_out>`` accumulated
+    into a (1, 1) output revisited by every grid step (TPU grids are
+    sequential, so the accumulation is well-defined).  Because the
     accumulator cotangent passes through later scan steps unchanged,
     ``d_out`` at step k IS the cotangent of the final aggregate, so
     ``dw_k = <g_k, dG>`` — exactly the through-aggregation hypergradient
     (g_k is recomputed under ``jax.checkpoint`` by the surrounding scan,
-    one client trajectory alive at a time).
+    one chunk of client trajectories alive at a time).
   * :func:`update_pass_bwd` — replays the optimizer recurrence from the
     saved (G, m, v, scalars) residuals and pushes the output cotangents
     (d new_p, d new_m, d new_v) back into gradient / opt-state cotangents
@@ -93,53 +80,7 @@ def _scalar_spec(cols: int, interpret: bool):
 
 
 # ---------------------------------------------------------------------------
-# Pass 1: weighted cohort reduce + global sum-of-squares
-# ---------------------------------------------------------------------------
-def _aggregate_kernel(w_ref, g_ref, out_ref, ssq_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        ssq_ref[0, 0] = jnp.float32(0.0)
-
-    g = g_ref[...]                                    # (cohort, br, LANES)
-    w = w_ref[...]                                    # (cohort, 1)
-    G = jnp.sum(g * w[:, :, None], axis=0)            # (br, LANES)
-    out_ref[...] = G
-    ssq_ref[0, 0] += jnp.sum(G * G)
-
-
-def aggregate_pass(g_stack: jax.Array, w_norm: jax.Array, *,
-                   block_rows: int = 256, interpret: bool = False
-                   ) -> Tuple[jax.Array, jax.Array]:
-    """g_stack: (cohort, rows, LANES) fp32; w_norm: (cohort,) normalized
-    weights.  Returns (G (rows, LANES) fp32, ssq () fp32)."""
-    cohort, rows, lanes = g_stack.shape
-    assert lanes == LANES, g_stack.shape
-    br = _block_rows(rows, block_rows)
-    G, ssq = pl.pallas_call(
-        _aggregate_kernel,
-        name="aggregate_pass",
-        grid=(rows // br,),
-        in_specs=[
-            pl.BlockSpec((cohort, 1), lambda i: (0, 0)),
-            pl.BlockSpec((cohort, br, LANES), lambda i: (0, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((br, LANES), lambda i: (i, 0)),
-            _scalar_spec(1, interpret),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(w_norm.astype(jnp.float32).reshape(cohort, 1), g_stack)
-    return G, ssq[0, 0]
-
-
-# ---------------------------------------------------------------------------
-# Streaming pass (scan strategy): acc <- acc + w_k * g_k in one HBM sweep
+# Pass 1 (streaming): acc <- acc + w_k * g_k in one HBM sweep
 # ---------------------------------------------------------------------------
 def _accumulate_kernel(w_ref, acc_ref, g_ref, out_ref):
     out_ref[...] = acc_ref[...] + w_ref[0, 0] * g_ref[...]
@@ -150,7 +91,7 @@ def accumulate_pass(acc: jax.Array, g: jax.Array, w, *,
                     ) -> jax.Array:
     """acc/g: (rows, LANES) fp32; w: scalar normalized client weight.
     Returns ``acc + w * g`` — the per-client streaming Eq. (14) term the
-    scan strategy carries instead of a pytree.
+    cohort core carries instead of a pytree.
 
     The result is written into ``acc``'s buffer.  Semantics are unchanged:
     where the caller still uses ``acc`` after the call (outside a jit, or
@@ -282,61 +223,6 @@ def update_pass(G: jax.Array, p: jax.Array, m: Optional[jax.Array],
     new_m = outs[1] if len(outs) > 1 else None
     new_v = outs[2] if len(outs) > 2 else None
     return new_p, new_m, new_v
-
-
-# ---------------------------------------------------------------------------
-# Backward pass 1: cotangent-of-mean scatter + per-client weight cotangents
-# ---------------------------------------------------------------------------
-def _aggregate_bwd_kernel(w_ref, dssq_ref, g_ref, G_ref, dG_ref,
-                          dg_ref, dw_ref):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        dw_ref[...] = jnp.zeros_like(dw_ref)
-
-    # total mean cotangent: forward was G = sum_k w_k g_k, ssq = <G, G>
-    dGt = dG_ref[...] + 2.0 * dssq_ref[0, 0] * G_ref[...]     # (br, LANES)
-    dg_ref[...] = w_ref[...][:, :, None] * dGt[None, :, :]    # dg_k = w_k dGt
-    dw_ref[...] += jnp.sum(jnp.sum(g_ref[...] * dGt[None, :, :], axis=2),
-                           axis=1, keepdims=True)             # dw_k = <g_k,dGt>
-
-
-def aggregate_pass_bwd(g_stack: jax.Array, w_norm: jax.Array, G: jax.Array,
-                       dG: jax.Array, dssq: jax.Array, *,
-                       block_rows: int = 256, interpret: bool = False
-                       ) -> Tuple[jax.Array, jax.Array]:
-    """VJP of :func:`aggregate_pass` w.r.t. (g_stack, w_norm).
-
-    g_stack/(dG, dssq): primals/cotangents as produced by the forward; G is
-    the saved forward output.  Returns (dg_stack (cohort, rows, LANES),
-    dw (cohort,))."""
-    cohort, rows, lanes = g_stack.shape
-    assert lanes == LANES, g_stack.shape
-    br = _block_rows(rows, block_rows)
-    dg, dw = pl.pallas_call(
-        _aggregate_bwd_kernel,
-        name="aggregate_pass_bwd",
-        grid=(rows // br,),
-        in_specs=[
-            pl.BlockSpec((cohort, 1), lambda i: (0, 0)),
-            _scalar_spec(1, interpret),
-            pl.BlockSpec((cohort, br, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((br, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((br, LANES), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((cohort, br, LANES), lambda i: (0, i, 0)),
-            pl.BlockSpec((cohort, 1), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((cohort, rows, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((cohort, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(w_norm.astype(jnp.float32).reshape(cohort, 1),
-      dssq.astype(jnp.float32).reshape(1, 1), g_stack, G, dG)
-    return dg, dw[:, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -1,19 +1,18 @@
 """Public engine for the fused server-side update.
 
-:func:`fused_server_update` replaces the legacy 5+ tree-traversal server
-step (``weighted_mean`` -> clip-norm scale -> fp32 cast -> optimizer ``upd``
--> param write) with exactly two HBM sweeps over flat per-dtype-group fp32
-buffers (layout: ``repro.core.flat``):
+Replaces the legacy 5+ tree-traversal server step (weighted mean ->
+clip-norm scale -> fp32 cast -> optimizer ``upd`` -> param write) with two
+kinds of HBM sweep over flat per-dtype-group fp32 buffers (layout:
+``repro.core.flat``):
 
-  pass 1  kernels.aggregate_pass   cohort-weighted mean + ||G||^2
+  pass 1  kernels.accumulate_pass  one FMA per client, acc + w_k g_k,
+                                   inside the cohort core's scan
+                                   (:func:`flat_accumulate`)
   pass 2  kernels.update_pass      clip scale + sgd/sgdm/adam/yogi + write
+                                   (:func:`flat_apply_groups`)
 
-The client-sequential (scan) strategy streams pass 1 instead: the cohort
-scan carries the flat group buffers and FMAs each client's flattened
-gradient into them with :func:`flat_accumulate`
-(``kernels.accumulate_pass``), then :func:`fused_apply_flat` runs pass 2
-on the result — same engine, no stacked (cohort, rows, LANES) tensor ever
-materializes.
+so at most one chunk of flat client gradients is alive, never the
+whole cohort's.
 
 Numerics match ``repro.core.server_opt.apply`` on the clipped fp32 mean to
 <= 1e-5 relative (tested against both the pure-jnp ``ref`` oracle and the
@@ -21,18 +20,18 @@ legacy tree-map path).  ``use_ref=True`` swaps the Pallas kernels for the
 oracle; ``interpret`` defaults to True off-TPU so the same code path runs
 in the CPU tier-1 suite.
 
-The engine is **differentiable**: each kernel pair is wrapped in a
-``jax.custom_vjp`` (:func:`_agg_vjp` / :func:`_upd_vjp`) whose backward is
-the hand-written ``aggregate_pass_bwd`` / ``update_pass_bwd`` Pallas
+The engine is **differentiable**: each kernel is wrapped in a
+``jax.custom_vjp`` (:func:`_acc_vjp` / :func:`_upd_vjp`) whose backward is
+the hand-written ``accumulate_pass_bwd`` / ``update_pass_bwd`` Pallas
 kernel (or the matching ``ref`` oracle under ``use_ref=True``), so
-``jax.grad`` through :func:`fused_server_update` — w.r.t. the stacked
-per-client gradients, the client weights, the learning rate and the
-parameters — costs two more flat HBM sweeps instead of XLA
-re-differentiating the engine.  Only the tiny scalar glue (weight
-normalization, ||G||, clip scale, bias corrections) is left to XLA.  This
-is what powers ``meta_mode="through_aggregation"`` (``core/meta.py``):
-hypergradients of the meta loss w.r.t. per-client aggregation weights and
-the server step size.
+``jax.grad`` through a round's server step — w.r.t. the client weights,
+the per-client gradients, the learning rate and the parameters — costs
+flat HBM sweeps instead of XLA re-differentiating the engine.  Only the
+tiny scalar glue (weight normalization, ||G||, clip scale, bias
+corrections) is left to XLA.  This is what powers
+``meta_mode="through_aggregation"`` (``core/meta.py``): hypergradients of
+the meta loss w.r.t. per-client aggregation weights and the server step
+size.
 """
 from __future__ import annotations
 
@@ -44,7 +43,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import flat as flat_mod
-from repro.core.flat import FlatSpec, make_flat_spec
+from repro.core.flat import FlatSpec
 from repro.kernels.fused_update import kernel as K
 from repro.kernels.fused_update import ref as R
 
@@ -71,37 +70,11 @@ def init_flat_opt_state(opt: str, spec: FlatSpec) -> PyTree:
 
 
 @functools.lru_cache(maxsize=None)
-def _agg_vjp(use_ref: bool, interpret: bool):
-    """custom_vjp over the aggregate pass: (g_stack, w_norm) -> (G, ssq)."""
-
-    @jax.custom_vjp
-    def agg(g_stack, w_norm):
-        if use_ref:
-            return R.aggregate_ref(g_stack, w_norm)
-        return K.aggregate_pass(g_stack, w_norm, interpret=interpret)
-
-    def fwd(g_stack, w_norm):
-        G, ssq = agg(g_stack, w_norm)
-        return (G, ssq), (g_stack, w_norm, G)
-
-    def bwd(res, cts):
-        g_stack, w_norm, G = res
-        dG, dssq = cts
-        if use_ref:
-            return R.aggregate_bwd_ref(g_stack, w_norm, G, dG, dssq)
-        return K.aggregate_pass_bwd(g_stack, w_norm, G, dG, dssq,
-                                    interpret=interpret)
-
-    agg.defvjp(fwd, bwd)
-    return agg
-
-
-@functools.lru_cache(maxsize=None)
 def _acc_vjp(use_ref: bool, interpret: bool):
     """custom_vjp over the streaming accumulate: (acc, g, w) -> acc + w*g.
 
-    The scan strategy carries the flat group buffers and calls this once
-    per client per group.  The backward is (d_acc, w*d_out, <g, d_out>) —
+    The cohort core's scan carries the flat group buffers and calls this
+    once per client per group.  The backward is (d_acc, w*d_out, <g, d_out>) —
     the accumulator cotangent is the identity, so the cotangent arriving at
     step k is the cotangent of the FINAL aggregate, making dw_k = <g_k, dG>
     the through-aggregation weight hypergradient."""
@@ -173,31 +146,6 @@ def _upd_vjp(opt: str, momentum: float, b1: float, b2: float, eps: float,
     return upd
 
 
-def flat_weighted_aggregate(spec: FlatSpec, grad_stack: PyTree,
-                            client_weights: jax.Array, *,
-                            use_ref: bool = False,
-                            interpret: Optional[bool] = None
-                            ) -> Tuple[list, jax.Array]:
-    """Pass 1 alone: normalize ``client_weights``, flatten the stacked
-    per-client gradients and run the differentiable aggregate kernel per
-    dtype group.  Returns (G_groups, ssq) where ``ssq = ||G||^2`` summed
-    over groups — exactly the interior of :func:`fused_server_update`, so
-    cohort executors can produce the Eq. (14) flat weighted mean as a
-    uniform handle and leave pass 2 to the server engine."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    w = client_weights.astype(jnp.float32)
-    w = w / jnp.maximum(jnp.sum(w), 1e-30)
-    g_groups = flat_mod.flatten_stacked(spec, grad_stack)
-    agg = _agg_vjp(use_ref, interpret)
-    Gs, ssq = [], jnp.float32(0.0)
-    for g_stack in g_groups:
-        G, s = agg(g_stack, w)
-        Gs.append(G)
-        ssq = ssq + s
-    return Gs, ssq
-
-
 def flat_apply_groups(spec: FlatSpec, G_groups, gn, params: PyTree,
                       opt_state: PyTree, *, opt: str, lr,
                       clip_norm: float = 0.0, momentum: float = 0.9,
@@ -205,82 +153,13 @@ def flat_apply_groups(spec: FlatSpec, G_groups, gn, params: PyTree,
                       use_ref: bool = False,
                       interpret: Optional[bool] = None
                       ) -> Tuple[PyTree, PyTree, jax.Array]:
-    """Pass 2 alone (public form of the shared ``_apply_groups``): clip
-    scale + optimizer + param write over aggregated flat buffers, with the
-    pre-clip global norm ``gn`` supplied by the caller (the aggregate
-    kernel's ssq, or :func:`repro.core.flat.flat_sq_norm` for streamed
-    accumulations).  Returns (new_params, new_opt_state, gn_after_clip)."""
+    """Pass 2: clip scale + optimizer + param write over the aggregated
+    flat dtype-group buffers ``G_groups``, with the pre-clip global norm
+    ``gn`` supplied by the caller (the square root of
+    :func:`repro.core.flat.flat_sq_norm`).  Returns (new_params,
+    new_opt_state, gn_after_clip)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    return _apply_groups(spec, list(G_groups), gn, params, opt_state,
-                         opt=opt, lr=lr, clip_norm=clip_norm,
-                         momentum=momentum, b1=b1, b2=b2, eps=eps,
-                         use_ref=use_ref, interpret=interpret)
-
-
-def fused_server_update(params: PyTree, grad_stack: PyTree,
-                        client_weights: jax.Array, opt_state: PyTree, *,
-                        opt: str = "sgd", lr, clip_norm: float = 0.0,
-                        momentum: float = 0.9, b1: float = 0.9,
-                        b2: float = 0.99, eps: float = 1e-8,
-                        spec: Optional[FlatSpec] = None,
-                        use_ref: bool = False,
-                        interpret: Optional[bool] = None
-                        ) -> Tuple[PyTree, PyTree, jax.Array]:
-    """One fused server step over stacked per-client gradients.
-
-    grad_stack: pytree matching ``params`` with a leading cohort axis on
-    every leaf; client_weights: (cohort,) n_k (un-normalized);
-    opt_state: flat state from :func:`init_flat_opt_state`.
-    Returns (new_params, new_opt_state, grad_norm_after_clip)."""
-    if spec is None:
-        spec = make_flat_spec(params)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    Gs, ssq = flat_weighted_aggregate(spec, grad_stack, client_weights,
-                                      use_ref=use_ref, interpret=interpret)
-
-    return _apply_groups(spec, Gs, jnp.sqrt(ssq), params, opt_state,
-                         opt=opt, lr=lr, clip_norm=clip_norm,
-                         momentum=momentum, b1=b1, b2=b2, eps=eps,
-                         use_ref=use_ref, interpret=interpret)
-
-
-def fused_apply_flat(params: PyTree, G_groups, opt_state: PyTree, *,
-                     opt: str = "sgd", lr, clip_norm: float = 0.0,
-                     momentum: float = 0.9, b1: float = 0.9,
-                     b2: float = 0.99, eps: float = 1e-8,
-                     spec: Optional[FlatSpec] = None,
-                     use_ref: bool = False,
-                     interpret: Optional[bool] = None
-                     ) -> Tuple[PyTree, PyTree, jax.Array]:
-    """The clip+optimizer+write half of the engine over ALREADY-aggregated
-    flat buffers — the scan strategy's entry point, where pass 1 happened
-    as K streaming :func:`flat_accumulate` FMAs inside the cohort scan.
-
-    G_groups: one (rows, LANES) fp32 buffer per dtype group of ``spec``
-    holding the Eq. (14) weighted mean.  ||G||^2 is reduced here with plain
-    jnp (one extra flat read; its VJP is the trivial 2G so no kernel is
-    warranted).  Returns (new_params, new_opt_state, grad_norm_after_clip)
-    exactly like :func:`fused_server_update`."""
-    if spec is None:
-        spec = make_flat_spec(params)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    gn = jnp.sqrt(flat_mod.flat_sq_norm(G_groups))
-    return _apply_groups(spec, list(G_groups), gn, params,
-                         opt_state, opt=opt, lr=lr, clip_norm=clip_norm,
-                         momentum=momentum, b1=b1, b2=b2, eps=eps,
-                         use_ref=use_ref, interpret=interpret)
-
-
-def _apply_groups(spec: FlatSpec, Gs, gn, params: PyTree, opt_state: PyTree,
-                  *, opt: str, lr, clip_norm: float, momentum: float,
-                  b1: float, b2: float, eps: float, use_ref: bool,
-                  interpret: bool) -> Tuple[PyTree, PyTree, jax.Array]:
-    """Shared pass 2: clip scale + optimizer + param write over the flat
-    dtype groups.  ``gn`` is the pre-clip global gradient norm."""
     upd = _upd_vjp(opt, momentum, b1, b2, eps, use_ref, interpret)
     p_groups = flat_mod.flatten_tree(spec, params)
 
@@ -302,7 +181,7 @@ def _apply_groups(spec: FlatSpec, Gs, gn, params: PyTree, opt_state: PyTree,
     ms = opt_state.get("m", (None,) * len(spec.groups))
     vs = opt_state.get("v", (None,) * len(spec.groups))
     new_p, new_m, new_v = [], [], []
-    for g, G, p, m, v in zip(spec.groups, Gs, p_groups, ms, vs):
+    for g, G, p, m, v in zip(spec.groups, G_groups, p_groups, ms, vs):
         fn = upd
         if spec.mesh is not None:
             # Mosaic kernels are not partitioned automatically: run the

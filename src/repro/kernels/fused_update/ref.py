@@ -3,18 +3,15 @@
 Operates on the flat-buffer layout of ``repro.core.flat`` with exactly the
 two-pass structure of the Pallas kernels:
 
-  pass 1  (aggregate):  G = sum_k w_k g_k   and   ssq = ||G||^2
+  pass 1  (accumulate): acc + w_k g_k, one client at a time
   pass 2  (apply):      d = optimizer(G * scale);  p <- p - lr * d
-
-plus the scan strategy's streaming form of pass 1 (:func:`accumulate_ref`:
-``acc + w_k g_k``, one client at a time).
 
 The per-optimizer math mirrors ``repro.core.server_opt.apply`` line for
 line (fp32 throughout); bias corrections for adam/yogi arrive as the
 precomputed scalars bc1 = 1/(1-b1^t), bc2 = 1/(1-b2^t).
 
-:func:`aggregate_bwd_ref` / :func:`update_bwd_ref` are the matching
-hand-derived VJPs — the oracles for ``kernel.aggregate_pass_bwd`` /
+:func:`accumulate_bwd_ref` / :func:`update_bwd_ref` are the matching
+hand-derived VJPs — the oracles for ``kernel.accumulate_pass_bwd`` /
 ``kernel.update_pass_bwd`` and the ``use_ref=True`` arm of the
 ``jax.custom_vjp`` ops in ``ops.py``.  Same conventions as the kernels:
 yogi's ``sign`` is locally constant and the adam/yogi ``1/(2 sqrt)``
@@ -28,17 +25,9 @@ import jax
 import jax.numpy as jnp
 
 
-def aggregate_ref(g_stack: jax.Array, w_norm: jax.Array
-                  ) -> Tuple[jax.Array, jax.Array]:
-    """g_stack: (cohort, rows, lanes) fp32; w_norm: (cohort,) normalized.
-    Returns (G (rows, lanes) fp32, ssq scalar fp32)."""
-    G = jnp.sum(g_stack * w_norm[:, None, None].astype(jnp.float32), axis=0)
-    return G, jnp.sum(G * G)
-
-
 def accumulate_ref(acc: jax.Array, g: jax.Array, w) -> jax.Array:
-    """Streaming Eq. (14) term (scan strategy): ``acc + w * g`` over one
-    client's flat (rows, lanes) fp32 gradient buffer."""
+    """Streaming Eq. (14) term: ``acc + w * g`` over one client's flat
+    (rows, lanes) fp32 gradient buffer."""
     return acc + jnp.asarray(w, jnp.float32) * g
 
 
@@ -76,16 +65,6 @@ def update_ref(G: jax.Array, p: jax.Array, m: Optional[jax.Array],
         step = (m_new * bc1) / (jnp.sqrt(v_new * bc2) + eps)
         return p - lr * step, m_new, v_new
     raise ValueError(opt)
-
-
-def aggregate_bwd_ref(g_stack: jax.Array, w_norm: jax.Array, G: jax.Array,
-                      dG: jax.Array, dssq) -> Tuple[jax.Array, jax.Array]:
-    """VJP of :func:`aggregate_ref`: dg_k = w_k (dG + 2 dssq G),
-    dw_k = <g_k, dG + 2 dssq G>.  Returns (dg_stack, dw (cohort,))."""
-    dGt = dG + 2.0 * jnp.float32(dssq) * G
-    dg = w_norm[:, None, None].astype(jnp.float32) * dGt[None]
-    dw = jnp.sum(g_stack * dGt[None], axis=(1, 2))
-    return dg, dw
 
 
 def update_bwd_ref(G: jax.Array, m: Optional[jax.Array],

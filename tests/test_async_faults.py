@@ -130,9 +130,9 @@ def test_async_cleanroom_tracks_vmap_base():
                                     async_capacity=COHORT)
     s_sync, _ = _run_rounds(model, fed_sync, 2)
     s_async, _ = _run_rounds(model, fed_async, 2)
-    # the vmap executor aggregates in parallel (flat_weighted_aggregate)
-    # while the pool flush streams sequentially: same math, different
-    # reduction order -> fp32 tolerance, not bit-identity
+    # the sync round runs its clients in one vmapped chunk while the async
+    # vmap base and pool flush run theirs through separate programs: the
+    # same math, compiled apart -> fp32 tolerance, not bit-identity
     for a, b in zip(jax.tree.leaves(s_sync["params"]),
                     jax.tree.leaves(s_async["params"])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5,

@@ -7,10 +7,10 @@ over) — the properties the refactor must keep forever:
     agree across chunk in {1, 3, cohort} on {legacy_tree, fused_flat} x
     {post, through_aggregation}, including rounds_per_call > 1 and the
     ragged cohort % chunk != 0 case (zero-weight padding);
-  * pre-refactor streaming compat: chunk=1 == cohort_strategy='scan';
-    chunk=cohort matches the vmap executor <= 1e-5 (the vmap aggregate
-    kernel reduces the cohort axis in XLA reduce-tree order — equal in
-    exact arithmetic, ~1 ulp of reassociation in float);
+  * vmap and scan are pins of the same core: the vmap executor runs it at
+    chunk = cohort and the scan executor at chunk = 1, so each equals the
+    chunked executor at that chunk bitwise (and chunk = cohort is the
+    default FedConfig's round);
   * rng audit: the participation and fault streams fold out of the ROUND
     rng before the executor runs, so partial participation and fault
     injection are chunking-invariant bitwise (counts and state);
@@ -138,10 +138,10 @@ def test_ragged_final_chunk_pads_with_zero_weight(key):
 
 
 # ---------------------------------------------------------------------------
-# pre-refactor compat
+# vmap and scan: chunk-size pins of the one core
 # ---------------------------------------------------------------------------
 def test_chunk1_matches_scan_strategy_bitwise(key):
-    """chunk=1 IS the pre-refactor scan streaming round."""
+    """chunk=1 IS the scan executor's round."""
     model = make_mlp_model()
     _assert_identical(_run(model, _fed(1), key),
                       _run(model, _fed(None, cohort_strategy="scan"), key))
@@ -149,19 +149,27 @@ def test_chunk1_matches_scan_strategy_bitwise(key):
 
 @pytest.mark.parametrize("mode", ["post", "through_aggregation"])
 def test_chunk_eq_cohort_matches_vmap(key, mode):
-    """chunk=cohort vs the vmap executor: identical in exact arithmetic;
-    <= 1e-5 in float (kernel reduce-tree vs client-order reassociation)."""
+    """chunk=cohort vs the default FedConfig (cohort_chunk=None, the vmap
+    executor): the same core at the same chunk, identical to the bit."""
     model = make_mlp_model()
-    (st_c, m_c) = _run(model, _fed(COHORT, mode=mode), key)
-    (st_v, m_v) = _run(model, _fed(None, mode=mode), key)
-    for a, b in zip(jax.tree.leaves(st_c), jax.tree.leaves(st_v)):
-        np.testing.assert_allclose(np.asarray(a, np.float32),
-                                   np.asarray(b, np.float32),
-                                   rtol=1e-5, atol=1e-6)
-    for name in m_c:
-        np.testing.assert_allclose(np.asarray(m_c[name]),
-                                   np.asarray(m_v[name]),
-                                   rtol=1e-4, atol=1e-6, err_msg=name)
+    _assert_identical(_run(model, _fed(COHORT, mode=mode), key),
+                      _run(model, _fed(None, mode=mode), key))
+
+
+@pytest.mark.parametrize("mode", ["post", "through_aggregation"])
+@pytest.mark.parametrize("strategy,chunk", [("vmap", COHORT), ("scan", 1)])
+def test_vmap_and_scan_are_chunk_pins(key, strategy, chunk, mode):
+    """The vmap and scan registrations resolve to the chunked core pinned at
+    chunk = cohort and chunk = 1, and two chained rounds equal the chunked
+    executor at that chunk: params, opt, ctrl and every metric, bitwise."""
+    from repro.core.executors import ChunkedExecutor, resolve_executor
+    fed = _fed(None, mode=mode, cohort_strategy=strategy)
+    exe = resolve_executor(fed)
+    assert exe.name == strategy and isinstance(exe, ChunkedExecutor)
+    assert exe._chunk_for(COHORT) == chunk
+    model = make_mlp_model()
+    _assert_identical(_run(model, fed, key),
+                      _run(model, _fed(chunk, mode=mode), key))
 
 
 # ---------------------------------------------------------------------------

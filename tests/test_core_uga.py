@@ -5,10 +5,11 @@ System invariants:
       one-step case is exactly Eq. (7); unbiasedness base case).
   I2  The HVP-form UGA equals straight autodiff through the keep-trace
       trajectory (implementation equivalence — exact same math).
-  I3  Client-parallel (vmap) and client-sequential (scan) cohorts produce
-      the same aggregate.
+  I3  Client-parallel (chunk = cohort) and client-sequential (chunk = 1)
+      cohorts produce the same aggregate.
   I4  FedProx with mu=0 is exactly FedAvg.
-  I5  Weighted aggregation is permutation-invariant and respects weights.
+  I5  The cohort core's weighted aggregation (Eq. 14) is permutation-
+      invariant and respects weights.
   I6  FedMeta's update moves params along -grad of the meta loss.
   I7  UGA == FedAvg pseudo-gradient direction at lr->0, E=2 (both reduce to
       the sum of microbatch gradients at w_t).
@@ -21,12 +22,23 @@ try:
 except ImportError:  # property tests skip; unit tests still run
     from _hypothesis_stub import given, settings, st
 
-from repro.core.aggregate import cohort_gradient, weighted_mean
+from repro.core.aggregate import chunked_cohort_gradient_flat
 from repro.core.client import (fedavg_update, make_client_update, uga_update,
                                uga_update_autodiff)
+from repro.core.flat import make_flat_spec, unflatten_tree
 from repro.core.meta import meta_update
 
 SETTINGS = dict(max_examples=12, deadline=None)
+
+
+def cohort_gradient(client_update, w, batch, weights, lr, chunk=None):
+    """The streaming cohort core's aggregate as a pytree (chunk defaults to
+    the whole cohort).  Returns (G, weighted mean client loss)."""
+    spec = make_flat_spec(w)
+    chunk = weights.shape[0] if chunk is None else chunk
+    G, loss = chunked_cohort_gradient_flat(client_update, w, batch, weights,
+                                           lr, None, spec=spec, chunk=chunk)
+    return unflatten_tree(spec, G), loss
 
 
 def quad_loss(w, batch, rng=None):
@@ -51,7 +63,7 @@ def _problem(seed, cohort=3, b=8, d=5, h=7):
 def test_I1_uga_e1_unbiased(seed):
     w, batch, weights = _problem(seed)
     cu = make_client_update("uga", quad_loss, local_steps=1)
-    G, _ = cohort_gradient(cu, w, batch, weights, 0.05, None)
+    G, _ = cohort_gradient(cu, w, batch, weights, 0.05)
     # central gradient on the weighted pooled distribution
     def pooled(w0):
         per = jax.vmap(lambda bx, by: quad_loss(w0, {"x": bx, "y": by})[0])(
@@ -82,10 +94,8 @@ def test_I2_hvp_equals_autodiff(seed, steps, epochs):
 def test_I3_vmap_equals_scan(seed, algo):
     w, batch, weights = _problem(seed, cohort=4)
     cu = make_client_update(algo, quad_loss, local_steps=2)
-    Gv, lv = cohort_gradient(cu, w, batch, weights, 0.05, None,
-                             strategy="vmap")
-    Gs, ls = cohort_gradient(cu, w, batch, weights, 0.05, None,
-                             strategy="scan")
+    Gv, lv = cohort_gradient(cu, w, batch, weights, 0.05, chunk=4)
+    Gs, ls = cohort_gradient(cu, w, batch, weights, 0.05, chunk=1)
     np.testing.assert_allclose(lv, ls, rtol=1e-5)
     for a, b in zip(jax.tree.leaves(Gv), jax.tree.leaves(Gs)):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4)
@@ -110,16 +120,25 @@ def test_I5_weighted_mean_properties(seed):
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.normal(size=(5, 4, 3)), jnp.float32)
     wgt = jnp.asarray(rng.integers(1, 9, 5), jnp.float32)
-    m = weighted_mean({"x": x}, wgt)["x"]
+    w0 = {"x": jnp.zeros((4, 3), jnp.float32)}
+
+    def returns_batch(w, batch, lr, r):          # client k's "gradient"
+        return batch, jnp.float32(0.0)           # is its batch x[k]
+
+    def weighted_mean(xs, weights):
+        return cohort_gradient(returns_batch, w0, {"x": xs}, weights,
+                               0.0)[0]["x"]
+
+    m = weighted_mean(x, wgt)
     # permutation invariance
     perm = rng.permutation(5)
-    m2 = weighted_mean({"x": x[perm]}, wgt[perm])["x"]
+    m2 = weighted_mean(x[perm], wgt[perm])
     np.testing.assert_allclose(m, m2, atol=1e-6)
     # scale invariance of weights
-    m3 = weighted_mean({"x": x}, wgt * 7.0)["x"]
+    m3 = weighted_mean(x, wgt * 7.0)
     np.testing.assert_allclose(m, m3, atol=1e-6)
     # equal weights == plain mean
-    m4 = weighted_mean({"x": x}, jnp.ones(5))["x"]
+    m4 = weighted_mean(x, jnp.ones(5))
     np.testing.assert_allclose(m4, jnp.mean(x, 0), atol=1e-6)
 
 
@@ -173,9 +192,9 @@ def test_gradient_bias_is_real_and_uga_removes_it():
 
     true_g = jax.grad(uga_objective)(w)
     cu = make_client_update("uga", quad_loss, local_steps=2)
-    G_uga, _ = cohort_gradient(cu, w, batch, weights, lr, None)
+    G_uga, _ = cohort_gradient(cu, w, batch, weights, lr)
     fa = make_client_update("fedavg", quad_loss, local_steps=2)
-    G_fa, _ = cohort_gradient(fa, w, batch, weights, lr, None)
+    G_fa, _ = cohort_gradient(fa, w, batch, weights, lr)
 
     assert cos(G_uga, true_g) > 0.9999           # unbiased
     assert cos(G_fa, true_g) < cos(G_uga, true_g)  # fedavg is biased

@@ -2,18 +2,20 @@
 
   * flat-buffer round-trip preserves structure/shapes/dtypes;
   * fused Pallas kernels == pure-jnp ref oracle == legacy tree-map path
-    for all four server optimizers, with and without clipping;
-  * the custom-VJP backward: ``jax.grad`` through ``fused_server_update``
-    (w.r.t. per-client gradient stack, client weights, server lr) ==
-    autodiff through the legacy tree-map path, for both the Pallas bwd
-    kernels and the ref oracle bwd;
+    for all four server optimizers, with and without clipping (the fused
+    step as the cohort core feeds it: one streaming ``flat_accumulate`` per
+    client, then ``flat_apply_groups``);
+  * the custom-VJP backward: ``jax.grad`` through that fused step (w.r.t.
+    per-client gradient stack, client weights, server lr) == autodiff
+    through the legacy tree-map path, for both the Pallas bwd kernels and
+    the ref oracle bwd;
   * rounds_per_call>1 (lax.scan driver) == K sequential single-round calls;
   * the modulo-indexed epoch schedule == the old jnp.tile expansion;
-  * scan-strategy cohort fusion: the streaming flat accumulation
-    (``accumulate_pass`` + custom VJP) produces BIT-identical aggregates to
-    the legacy pytree scan carry, and the fused scan round matches the
-    legacy scan round end to end (warm adam/yogi state per the sign-step
-    conditioning caveat the vmap tests document).
+  * the streaming cohort core: its flat accumulation (``accumulate_pass``
+    + custom VJP) produces BIT-identical aggregates to a plain pytree scan
+    carry, and the fused scan round matches the legacy scan round end to
+    end (warm adam/yogi state per the sign-step conditioning caveat the
+    vmap tests document).
 """
 import jax
 import jax.numpy as jnp
@@ -23,13 +25,14 @@ import pytest
 from repro.configs.base import FedConfig
 from repro.core import flat as F
 from repro.core import init_server_state, make_federated_round, server_opt
-from repro.core.aggregate import (cohort_gradient, scan_cohort_gradient_flat,
-                                  weighted_mean)
+from repro.core.aggregate import chunked_cohort_gradient_flat
 from repro.core.client import (fedavg_update, make_client_update, uga_update)
 from repro.kernels.fused_update import kernel as K
 from repro.kernels.fused_update import ops as O
 from repro.kernels.fused_update import ref as R
 from repro.models.model import Model
+
+from _reference import weighted_mean
 
 
 def mixed_tree(key):
@@ -116,6 +119,25 @@ def test_unflatten_stacked_inverts_flatten_stacked(key):
 # ---------------------------------------------------------------------------
 # fused engine vs ref oracle vs legacy tree-map path
 # ---------------------------------------------------------------------------
+def fused_step(params, grad_stack, wts, opt_state, *, opt, lr, clip_norm,
+               momentum=0.9, use_ref=False):
+    """The fused server step over stacked per-client gradients as the
+    cohort core feeds it: one streaming ``flat_accumulate`` per client into
+    the flat groups, in client order, then pass 2 (``flat_apply_groups``).
+    Returns (new_params, new_opt_state, grad_norm_after_clip)."""
+    spec = F.make_flat_spec(params)
+    accum = O.flat_accumulate(use_ref)
+    w = wts.astype(jnp.float32)
+    w = w / jnp.maximum(jnp.sum(w), 1e-30)
+    stacks = F.flatten_stacked(spec, grad_stack)
+    Gs = F.zeros_flat(spec)
+    for k in range(w.shape[0]):
+        Gs = [accum(G, g[k], w[k]) for G, g in zip(Gs, stacks)]
+    return O.flat_apply_groups(
+        spec, Gs, jnp.sqrt(F.flat_sq_norm(Gs)), params, opt_state, opt=opt,
+        lr=lr, clip_norm=clip_norm, momentum=momentum, use_ref=use_ref)
+
+
 @pytest.mark.parametrize("opt", ["sgd", "sgdm", "adam", "yogi"])
 @pytest.mark.parametrize("clip", [0.0, 0.5])
 def test_fused_matches_ref_and_legacy(key, opt, clip):
@@ -133,9 +155,9 @@ def test_fused_matches_ref_and_legacy(key, opt, clip):
     out = {}
     for use_ref in (False, True):
         st = O.init_flat_opt_state(opt, spec)
-        newp, newst, gn = O.fused_server_update(
-            params, grads, wts, st, opt=opt, lr=lr, clip_norm=clip,
-            momentum=0.9, use_ref=use_ref)
+        newp, newst, gn = jax.jit(lambda p, g, w, s: fused_step(
+            p, g, w, s, opt=opt, lr=lr, clip_norm=clip,
+            use_ref=use_ref))(params, grads, wts, st)
         out[use_ref] = (newp, gn)
     # Pallas kernels == oracle (same flat math, bit-level expectations loose)
     for a, b in zip(jax.tree.leaves(out[False][0]),
@@ -286,7 +308,8 @@ def assert_grads_close(got, want, tol=1e-5):
 @pytest.mark.parametrize("use_ref", [False, True])
 def test_grad_through_fused_matches_legacy_autodiff(key, opt, clip, use_ref):
     """d(objective)/d(grad_stack, client_weights, lr) through the fused
-    custom VJP == autodiff through the legacy tree-map path, where the
+    step's custom VJPs (the streaming accumulate and the update pass) ==
+    autodiff through the legacy tree-map path, where the
     objective touches new params, the clipped grad norm AND the new
     optimizer state (so every backward-kernel output cotangent is live).
 
@@ -323,9 +346,8 @@ def test_grad_through_fused_matches_legacy_autodiff(key, opt, clip, use_ref):
         if "v" in st:
             st["v"] = tuple(F.flatten_tree(spec, v_tree))
             st["t"] = jnp.asarray(t0, jnp.int32)
-        newp, newst, gn = O.fused_server_update(
-            params, g, w, st, opt=opt, lr=lr_, clip_norm=clip,
-            momentum=0.9, use_ref=use_ref)
+        newp, newst, gn = fused_step(params, g, w, st, opt=opt, lr=lr_,
+                                     clip_norm=clip, use_ref=use_ref)
         obj = _tree_dot(newp, c_p) + 0.3 * gn
         if "m" in newst:
             obj = obj + _flat_dot(newst["m"], c_m)
@@ -355,7 +377,7 @@ def test_grad_through_fused_matches_legacy_autodiff(key, opt, clip, use_ref):
             obj = obj + _tree_dot(newst["v"], c_v)
         return obj
 
-    fg = jax.grad(fused_obj, argnums=(0, 1, 2))(grads, wts, lr)
+    fg = jax.jit(jax.grad(fused_obj, argnums=(0, 1, 2)))(grads, wts, lr)
     lg = jax.grad(legacy_obj, argnums=(0, 1, 2))(grads, wts, lr)
     assert_grads_close(fg, lg)
 
@@ -374,8 +396,8 @@ def test_grad_wrt_params_through_fused_matches_legacy(key, opt):
 
     def fused_obj(p):
         st = O.init_flat_opt_state(opt, spec)
-        newp, _, _ = O.fused_server_update(p, grads, wts, st, opt=opt,
-                                           lr=0.07, clip_norm=0.5)
+        newp, _, _ = fused_step(p, grads, wts, st, opt=opt, lr=0.07,
+                                clip_norm=0.5)
         return _tree_dot(newp, c_p)
 
     def legacy_obj(p):
@@ -388,12 +410,12 @@ def test_grad_wrt_params_through_fused_matches_legacy(key, opt):
                                    G, 0.07)
         return _tree_dot(newp, c_p)
 
-    assert_grads_close(jax.grad(fused_obj)(params),
+    assert_grads_close(jax.jit(jax.grad(fused_obj))(params),
                        jax.grad(legacy_obj)(params))
 
 
 # ---------------------------------------------------------------------------
-# scan-strategy cohort fusion: streaming flat accumulation
+# the streaming cohort core: flat accumulation
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("use_ref", [False, True])
 def test_accumulate_pass_matches_formula_and_vjp(key, use_ref):
@@ -472,12 +494,37 @@ def test_streamed_aggregate_bitmatches_folded_formula(key, chunk):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def tree_scan_cohort_gradient(client_update, w_t, cohort_batch,
+                              client_weights, lr, rng):
+    """Plain pytree-carry cohort scan: one client at a time, ``acc + w_k *
+    g_k`` per leaf in fp32 with the weights normalized before the scan, the
+    weighted loss summed in client order."""
+    cohort = client_weights.shape[0]
+    rngs = jax.random.split(rng, cohort)
+    w32 = client_weights.astype(jnp.float32)
+    wn = w32 / jnp.maximum(jnp.sum(w32), 1e-30)
+
+    def body(carry, inp):
+        G_acc, l_acc = carry
+        batch, wk, r = inp
+        g_k, l_k = client_update(w_t, batch, lr, r)
+        G_acc = jax.tree.map(
+            lambda a, g: a + wk * g.astype(jnp.float32), G_acc, g_k)
+        return (G_acc, l_acc + wk * l_k), None
+
+    G0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), w_t)
+    (G, mean_loss), _ = jax.lax.scan(
+        jax.checkpoint(body, prevent_cse=False),
+        (G0, jnp.zeros((), jnp.float32)), (cohort_batch, wn, rngs))
+    return G, mean_loss
+
+
 @pytest.mark.parametrize("use_ref", [False, True])
 @pytest.mark.parametrize("algo", ["uga", "fedavg"])
 def test_scan_flat_cohort_bitmatches_legacy_carry(key, use_ref, algo):
-    """The streaming flat accumulation is the SAME fp32 math in the same
-    client order as the legacy pytree carry — the aggregate and the
-    weighted client loss must match bit for bit."""
+    """The streaming core at chunk 1 is the SAME fp32 math in the same
+    client order as a plain pytree carry — the aggregate and the weighted
+    client loss must match bit for bit."""
     model = make_mlp_model()
     params = model.init(key)
     spec = F.make_flat_spec(params)
@@ -486,10 +533,11 @@ def test_scan_flat_cohort_bitmatches_legacy_carry(key, use_ref, algo):
     wts = jnp.asarray(rng.uniform(1.0, 5.0, 4), jnp.float32)
     cu = make_client_update(algo, model.loss, local_steps=2)
 
-    G_legacy, l_legacy = jax.jit(lambda p: cohort_gradient(
-        cu, p, batch, wts, 0.05, key, strategy="scan"))(params)
-    G_flat, l_flat = jax.jit(lambda p: scan_cohort_gradient_flat(
-        cu, p, batch, wts, 0.05, key, spec=spec, use_ref=use_ref))(params)
+    G_legacy, l_legacy = jax.jit(lambda p: tree_scan_cohort_gradient(
+        cu, p, batch, wts, 0.05, key))(params)
+    G_flat, l_flat = jax.jit(lambda p: chunked_cohort_gradient_flat(
+        cu, p, batch, wts, 0.05, key, spec=spec, chunk=1,
+        use_ref=use_ref))(params)
     G_flat_tree = F.unflatten_tree(spec, G_flat)
     np.testing.assert_array_equal(np.asarray(l_flat), np.asarray(l_legacy))
     for a, b in zip(jax.tree.leaves(G_flat_tree), jax.tree.leaves(G_legacy)):

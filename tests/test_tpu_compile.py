@@ -68,7 +68,6 @@ def shapes(topo):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
 
     return {"rows": rows, "buf": sds((rows, 128)), "scalar": sds(()),
-            "stack": sds((COHORT, rows, 128)), "w": sds((COHORT,)),
             "scal4": sds((1, FK.N_SCALARS)),
             "q": sds((rows, 128), jnp.int8),
             "packed": sds((rows // CK.SIGN_PACK, 128), jnp.uint8)}
@@ -88,9 +87,6 @@ KERNELS = {
     "update_pass_bwd[adam]": (_adam(FK.update_pass_bwd),
                               ("buf", "buf", "buf", "scal4", "buf", "buf",
                                "buf")),
-    "aggregate_pass": (FK.aggregate_pass, ("stack", "w")),
-    "aggregate_pass_bwd": (FK.aggregate_pass_bwd,
-                           ("stack", "w", "buf", "buf", "scalar")),
     "quantize_i8_pass": (
         lambda g, inv, s: CK.quantize_i8_pass(g, inv, s, with_error=True),
         ("buf", "scalar", "scalar")),
@@ -175,15 +171,14 @@ IN_PLACE_CALLERS = {
 def test_streaming_core_accumulates_in_place_on_v5e(topo, shapes, caller):
     """The streaming cohort core at smollm-360m's flat width (chunk 1, a
     stand-in client gradient built from the parameters), plain, through the
-    gradient w.r.t. the client weights of its scan form, and with each
+    gradient w.r.t. the client weights, and with each
     lossy codec between client and accumulator: the accumulating call
     writes its output into its accumulator operand, so XLA copies no
     (rows, 128) fp32 accumulator per client."""
     from jax.sharding import SingleDeviceSharding
     from repro.comm.codecs import Int8Codec, Sign1BitCodec
     from repro.core.aggregate import (chunked_cohort_gradient_coded,
-                                      chunked_cohort_gradient_flat,
-                                      scan_cohort_gradient_flat)
+                                      chunked_cohort_gradient_flat)
 
     cohort, kernel, acc_operand = IN_PLACE_CALLERS[caller]
     one = SingleDeviceSharding(topo.devices[0])
@@ -211,9 +206,9 @@ def test_streaming_core_accumulates_in_place_on_v5e(topo, shapes, caller):
 
     def through_aggregation(p, b, wts):
         def meta_loss(wts):
-            G, loss = scan_cohort_gradient_flat(client, p, b, wts, 0.01,
-                                                None, spec=spec,
-                                                interpret=False)
+            G, loss = chunked_cohort_gradient_flat(
+                client, p, b, wts, 0.01, None, spec=spec, chunk=1,
+                interpret=False)
             return jnp.sum(G[0] * G[0]) + loss
         return jax.grad(meta_loss)(wts)
 
